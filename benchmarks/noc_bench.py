@@ -113,6 +113,7 @@ from repro import traffic
 from repro.core import fabric
 from repro.interface import Interface, StepStats
 from repro.interface import pipeline as interface_pipeline
+from repro.launch.cache import enable_compile_cache
 from repro.noc import placement, topology
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -944,6 +945,7 @@ def main(argv=None):
                          "compile/transfer/run/block spans to PATH "
                          "(repro.obs.trace)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     core_sweep = tuple(int(c) for c in str(args.cores).split(",") if c)
     tick_cores = tuple(int(c) for c in str(args.tick_cores).split(",") if c)
     chips_list = tuple(int(c) for c in str(args.chips).split(",") if c) \

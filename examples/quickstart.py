@@ -8,6 +8,7 @@ QUICKSTART_STEPS, QUICKSTART_GEN_STEPS, QUICKSTART_CKPT_DIR.
 
 import os
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -15,7 +16,8 @@ import jax
 
 STEPS = int(os.environ.get("QUICKSTART_STEPS", "30"))
 GEN_STEPS = int(os.environ.get("QUICKSTART_GEN_STEPS", "16"))
-CKPT_DIR = os.environ.get("QUICKSTART_CKPT_DIR", "/tmp/repro_quickstart")
+CKPT_DIR = os.environ.get("QUICKSTART_CKPT_DIR",
+                          os.path.join(tempfile.gettempdir(), "repro_quickstart"))
 
 from repro.ckpt.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, Pipeline
@@ -54,4 +56,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -41,7 +42,8 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--microbatch", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
+    ap.add_argument("--ckpt-dir", default=os.path.join(tempfile.gettempdir(),
+                                                       "repro_train_lm"))
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args()
 
@@ -72,4 +74,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,57 +1,18 @@
-"""JAX version-compatibility shims (single home, import from here).
+"""JAX mesh API re-exports (single home, import from here).
 
-The codebase targets the newer ambient-mesh API (`jax.set_mesh`,
-top-level `jax.shard_map`, `jax.sharding.AxisType`); older jax (< 0.5)
-lacks all three.  These shims fall back to the legacy global-mesh context
-and `jax.experimental.shard_map`, threading the active mesh in manually.
+The codebase targets the ambient-mesh API of the installed JAX (0.9):
+`jax.set_mesh`, top-level `jax.shard_map` and `jax.sharding.AxisType`.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-
-    def mesh_axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    AxisType = None
-
-    def mesh_axis_kwargs(n: int) -> dict:
-        return {}
+set_mesh = jax.set_mesh
+shard_map = jax.shard_map
 
 
-# Both shims key off ONE capability check (`jax.set_mesh`).  jax versions
-# with top-level `jax.shard_map` but no `jax.set_mesh` exist; gating the two
-# independently would pair our mesh-tracking set_mesh with a native shard_map
-# that never reads it, breaking every mesh-less shard_map call.
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-    shard_map = jax.shard_map
-else:
-    _ACTIVE_MESHES: list = []
-
-    @contextlib.contextmanager
-    def set_mesh(mesh):
-        _ACTIVE_MESHES.append(mesh)
-        try:
-            with mesh:      # legacy global-mesh context
-                yield mesh
-        finally:
-            _ACTIVE_MESHES.pop()
-
-    if hasattr(jax, "shard_map"):
-        _shard_map_impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map(f, mesh=None, *, in_specs, out_specs, **kw):
-        if mesh is None:
-            if not _ACTIVE_MESHES:
-                raise ValueError("no ambient mesh: pass mesh= or use set_mesh")
-            mesh = _ACTIVE_MESHES[-1]
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **kw)
+def mesh_axis_kwargs(n: int) -> dict:
+    """`jax.make_mesh` keywords giving all ``n`` axes the Auto type."""
+    return {"axis_types": (AxisType.Auto,) * n}

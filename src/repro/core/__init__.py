@@ -7,8 +7,10 @@
                  (Figs. 9-11), functional search + behavioural PPA models
 - event_router:  HAT-style hierarchical MoE token dispatch (beyond-paper)
 - fabric:        DEPRECATED shim over `repro.interface` (the unified,
-                 registry-driven core-interface API with compiled sessions)
+                 registry-driven core-interface API with compiled sessions);
+                 not imported here, since it imports `repro.interface`,
+                 whose config imports this package - import it by name
 - ppa:           calibration constants shared by the models
 """
 
-from repro.core import aer, arbiter, cam, event_router, fabric, ppa  # noqa: F401
+from repro.core import aer, arbiter, cam, event_router, ppa  # noqa: F401

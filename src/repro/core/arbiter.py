@@ -91,18 +91,19 @@ class ArbiterScheme:
         property-tests every policy against it.  When replacing
         ``grant_delay`` on a derived scheme, drop or replace
         ``tick_latency`` too - it encodes the built-in delays.
-    sparse_tick_latency(ctx) -> Optional[(buf, counts) -> (cores,) float32]
+    sparse_tick_latency(ctx) -> Optional[(buf, counts) -> (cores, 1) f32]
         optional factory of the *event-compacted* form of ``tick_latency``
         for the ``impl="pallas_sparse"`` tick: ``buf`` is a
         (cores, capacity + 1) buffer of ascending active addresses padded
         with ``ctx.n`` (`repro.kernels.sparse_tick.compact_events`) and
-        ``counts`` the (cores,) live event counts.  Must return exactly
-        the float32 values ``tick_latency`` yields on the equivalent
-        dense frame (the conformance grid holds the whole sparse tick
-        bit-identical to the dense oracle).  May return ``None`` when no
+        ``counts`` the (cores, 1) live event counts.  The policy runs
+        inside a TPU Pallas kernel, so it keeps every value 2-D.  Must
+        return exactly the float32 values ``tick_latency`` yields on the
+        equivalent dense frame (the conformance grid holds the whole
+        sparse tick bit-identical to the dense oracle).  May return ``None`` when no
         closed form applies at this ``ctx``; schemes without the policy
         cannot run ``impl="pallas_sparse"`` (sessions refuse at compile).
-    sparse_encode_energy(ctx) -> Optional[(buf, counts) -> (cores,) float32]
+    sparse_encode_energy(ctx) -> Optional[(buf, counts) -> (cores, 1) f32]
         the event-compacted form of ``encode_energy``, same contract:
         bit-identical per-core toggles/event from the compacted buffer.
     """
@@ -496,19 +497,33 @@ def _hier_tree_tick_latency(ctx):
 # Same closed forms as the dense `tick_latency` policies, re-derived from
 # the compacted event buffer the ``impl="pallas_sparse"`` tick carries:
 # ``buf`` (cores, capacity + 1) holds each core's active addresses in
-# ascending service order padded with ``ctx.n``, ``counts`` the live
-# totals.  Every quantity is an exact small integer in fp32, so the
-# results are bit-identical to the dense policies (asserted per scheme in
-# tests/test_sparse_tick.py) and the fused kernel can call these inside
-# its body.  Address prefixes use arithmetic right shifts (``4**l`` and
-# ``sqrt_n`` are powers of two wherever these policies apply), which
-# floor-divide correctly for the ``-1`` boundary sentinel.
+# ascending service order padded with ``ctx.n``, ``counts`` (cores, 1) the
+# live totals, and each policy returns (cores, 1) float32.  Every value
+# stays 2-D, because the policies run inside the `repro.kernels.
+# sparse_tick` Pallas kernel and the TPU lowering refuses 1-D values.
+# Every quantity is an exact small integer in fp32, so the results are
+# bit-identical to the dense policies (asserted per scheme in
+# tests/test_sparse_tick.py).  Address prefixes use arithmetic right
+# shifts (``4**l`` and ``sqrt_n`` are powers of two wherever these
+# policies apply), which floor-divide correctly for the ``-1`` boundary
+# sentinel.  Constants are Python scalars: the kernel body rejects
+# captured traced arrays.
 # ---------------------------------------------------------------------------
 
 
+def _shift_in(buf, fill, *, from_left: bool):
+    """``buf`` moved one slot along the event axis, ``fill`` shifted in.
+
+    ``from_left`` gives each slot its predecessor (slot 0 gets ``fill``);
+    otherwise each slot gets its successor (the last slot gets ``fill``).
+    """
+    edge = jnp.full((buf.shape[0], 1), fill, buf.dtype)
+    if from_left:
+        return jnp.concatenate([edge, buf[:, :-1]], axis=1)
+    return jnp.concatenate([buf[:, 1:], edge], axis=1)
+
+
 def _binary_tree_sparse_latency(ctx):
-    # Python-scalar constants only: these closures run *inside* the fused
-    # Pallas kernel body, which rejects captured traced arrays.
     per_grant = 2.0 * (ctx.lg - 1.0)
 
     def lat(buf, counts):
@@ -529,12 +544,17 @@ def _greedy_tree_sparse_latency(ctx):
 
 def _token_ring_sparse_latency(ctx):
     def lat(buf, counts):
-        top = jnp.max(jnp.where(buf < ctx.n, buf, -1), axis=1)
+        top = jnp.max(jnp.where(buf < ctx.n, buf, -1), axis=1, keepdims=True)
         return jnp.where(counts > 0, top.astype(jnp.float32) + 1.0, 0.0)
     return lat
 
 
 def _hier_ring_sparse_latency(ctx):
+    # The buffer is ascending, so sections appear in order and a
+    # section's largest lo-address is that of its last event: the event
+    # whose successor sits in another section (pads, ``n >> shift == s``,
+    # sit in none).  That replaces the dense policy's per-section
+    # scatter-max with one shifted compare.
     if ctx.sqrt_n * ctx.sqrt_n != ctx.n:
         return None           # top ring wraps inside the address space
     s = ctx.sqrt_n
@@ -542,21 +562,14 @@ def _hier_ring_sparse_latency(ctx):
 
     def lat(buf, counts):
         real = buf < ctx.n
-        hi = jnp.where(real, buf >> shift, s - 1)    # pads parked in-range
-        lo = buf & (s - 1)
-
-        def one(hi_c, lo_c, real_c):
-            lo_max = jnp.full((s,), jnp.int32(-1)).at[hi_c].max(
-                jnp.where(real_c, lo_c, -1))
-            occupied = lo_max >= 0
-            sec = jnp.arange(s)
-            s_first = jnp.min(jnp.where(occupied, sec, s))
-            s_last = jnp.max(jnp.where(occupied, sec, -1))
-            return (1.0 + s_first + 3.0 * (s_last - s_first) +
-                    jnp.sum(jnp.where(occupied, lo_max, 0))
-                    ).astype(jnp.float32)
-
-        return jnp.where(counts > 0, jax.vmap(one)(hi, lo, real), 0.0)
+        hi = buf >> shift
+        last = real & (_shift_in(buf, ctx.n, from_left=False) >> shift != hi)
+        lo_max_sum = jnp.sum(jnp.where(last, buf & (s - 1), 0), axis=1,
+                             keepdims=True)
+        s_first = jnp.min(jnp.where(real, hi, s), axis=1, keepdims=True)
+        s_last = jnp.max(jnp.where(real, hi, -1), axis=1, keepdims=True)
+        total = 1 + s_first + 3 * (s_last - s_first) + lo_max_sum
+        return jnp.where(counts > 0, total.astype(jnp.float32), 0.0)
     return lat
 
 
@@ -568,10 +581,9 @@ def _hier_tree_sparse_latency(ctx):
     def lat(buf, counts):
         real = buf < ctx.n
         cluster = buf >> shift
-        prev = jnp.concatenate(
-            [jnp.full((buf.shape[0], 1), -1, buf.dtype), cluster[:, :-1]],
-            axis=1)
-        q = jnp.sum(real & (cluster != prev), axis=1).astype(jnp.float32)
+        prev = _shift_in(cluster, -1, from_left=True)
+        q = jnp.sum(real & (cluster != prev), axis=1,
+                    keepdims=True).astype(jnp.float32)
         k = counts.astype(jnp.float32)
         return jnp.where(k > 0.0,
                          2.0 * ctx.levels + (k - 1.0) + (q - 1.0), 0.0)
@@ -582,7 +594,7 @@ def _flat_sparse_encode_energy(ctx):
     const = math.log2(ctx.n)
 
     def enc(buf, counts):
-        return jnp.full((buf.shape[0],), const, jnp.float32)
+        return jnp.full((buf.shape[0], 1), const, jnp.float32)
     return enc
 
 
@@ -595,12 +607,11 @@ def _hat_sparse_encode_energy(ctx):
     levels = ctx.levels
 
     def enc(buf, counts):
-        shifts = 2 * jnp.arange(levels)
-        prev = jnp.concatenate(
-            [jnp.full((buf.shape[0], 1), -1, buf.dtype), buf[:, :-1]],
-            axis=1)
-        changed = (buf[:, :, None] >> shifts) != (prev[:, :, None] >> shifts)
-        toggles = jnp.sum(jnp.where(changed, 2.0, 0.0), axis=(1, 2))
+        prev = _shift_in(buf, -1, from_left=True)
+        toggles = sum(
+            jnp.sum(jnp.where((buf >> 2 * l) != (prev >> 2 * l), 2.0, 0.0),
+                    axis=1, keepdims=True)
+            for l in range(levels))
         return toggles / jnp.float32(ctx.n)
     return enc
 

@@ -101,12 +101,13 @@ class InterfaceConfig:
              "pallas" (route the CAM match through the
              `repro.kernels.cam_search` kernel and the AER address stream
              through `repro.kernels.hat_encode`; falls back to interpret
-             mode off-TPU), or "pallas_sparse" (the fused
-             `repro.kernels.sparse_tick` event path: per-core event
-             compaction feeding one kernel for CAM gather + scatter +
-             arbiter latency + AER encode, with a dense fallback when a
-             core exceeds ``sparse_capacity`` events).  Currents and
-             stats are bit-identical across impls.
+             mode off-TPU), or "pallas_sparse" (the sparse event
+             path: per-core event compaction feeding the
+             `repro.kernels.sparse_tick` kernel for arbiter latency +
+             AER encode energy and event-indexed accounting, with a
+             dense fallback when a core exceeds ``sparse_capacity``
+             events).  Currents and stats are bit-identical across
+             impls.
     sparse_capacity: per-core event-buffer capacity for
              ``impl="pallas_sparse"``; ``None`` applies the
              `repro.kernels.sparse_tick.ops.default_capacity` heuristic

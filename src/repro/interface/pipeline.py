@@ -19,7 +19,7 @@ stream is produced by `repro.kernels.hat_encode`.  ``cfg.impl`` selects
 the match backend: ``"xla"`` (gather), ``"pallas"`` (the
 `repro.kernels.cam_search` kernel; interpret-mode off-TPU), or
 ``"pallas_sparse"`` (`_sparse_event_tick`: per-core event compaction
-feeding the fused `repro.kernels.sparse_tick` kernel, with a dense
+feeding the `repro.kernels.sparse_tick` arbiter kernel, with a dense
 fallback when a core overflows ``cfg.sparse_capacity`` - per-tick cost
 scales with events rather than fabric size, results stay bit-identical).
 
@@ -132,6 +132,19 @@ def _entry_drive(params, spikes_flat, routing: RoutingIndex, cfg):
     return (spikes_flat[routing.src_idx] & routing.active).astype(jnp.float32)
 
 
+def route_currents(entry_drive, weights, targets, n: int):
+    """Weighted scatter-add of the driven CAM entries into currents.
+
+    (cores, entries) drive/weights/targets -> (cores, n) float32.  Every
+    event-driven path (dense, sparse, chip-sharded) routes through this
+    one expression, so their currents are bit-identical by construction
+    on any backend, whatever order its scatter sums duplicates in.
+    """
+    return jax.vmap(
+        lambda c, t: jnp.zeros((n,), jnp.float32).at[t].add(c)
+    )(entry_drive * weights, targets)
+
+
 def _addr_streams(spikes, cfg, n):
     """(cores, n) int32 AER address streams (service order, padded with n)."""
     impl = getattr(cfg, "impl", "xla")
@@ -233,15 +246,17 @@ def sparse_accounting_stats(cfg, tables, counts, ev_idx, ev_w, latencies,
 
 def _sparse_event_tick(params, spikes, cfg, tables, arb_cfg, routing,
                        cam_cycle_ns, noc_scheme, unchecked=False):
-    """The ``impl="pallas_sparse"`` tick: compact, fuse, or fall back.
+    """The ``impl="pallas_sparse"`` tick: compact, then sparse or dense.
 
     Compacts the frame into per-core event buffers, then runs *one*
-    `jax.lax.cond`: the sparse branch feeds the buffers through the fused
-    `repro.kernels.sparse_tick` kernel plus event-indexed accounting; the
-    dense branch is the ordinary event-driven tick, taken whenever any
-    core fired more than ``sparse_capacity`` events this tick.  Both
-    branches produce bit-identical ``(currents, latencies, enc_per_core,
-    StepStats)``, so the fallback only changes cost, never results.
+    `jax.lax.cond`: the sparse branch feeds the buffers through the
+    `repro.kernels.sparse_tick` arbiter kernel, routes currents through
+    the shared `route_currents` gather/scatter, and accounts from the
+    events; the dense branch is the ordinary event-driven tick, taken
+    whenever any core fired more than ``sparse_capacity`` events this
+    tick.  Both branches produce bit-identical ``(currents, latencies,
+    enc_per_core, StepStats)``, so the fallback only changes cost, never
+    results.
 
     The per-tick ``cond`` itself is not free (XLA conditionals cost tens
     of microseconds per tick on CPU hosts), so callers that have already
@@ -265,11 +280,12 @@ def _sparse_event_tick(params, spikes, cfg, tables, arb_cfg, routing,
 
     def sparse_branch(_):
         with jax.named_scope("repro.sparse_tick"):
-            currents, latencies, enc_per_core, hits_total = \
-                sparse_ops.sparse_tick(
-                    spikes_flat, buf, counts, routing.src_idx, routing.active,
-                    params.weights, params.targets, n=n,
-                    latency_fn=latency_fn, encode_fn=encode_fn)
+            latencies, enc_per_core = sparse_ops.sparse_tick(
+                buf, counts, latency_fn=latency_fn, encode_fn=encode_fn)
+            entry_drive = _entry_drive(params, spikes_flat, routing, cfg)
+            currents = route_currents(entry_drive, params.weights,
+                                      params.targets, n)
+            hits_total = jnp.sum(entry_drive)
             ev_idx, ev_w = sparse_ops.event_indices(buf, n)
             stats = sparse_accounting_stats(
                 cfg, tables, counts, ev_idx, ev_w, latencies, enc_per_core,
@@ -280,10 +296,8 @@ def _sparse_event_tick(params, spikes, cfg, tables, arb_cfg, routing,
         with jax.named_scope("repro.sparse_dense_fallback"):
             latencies = arb.batched_tick_latency(arb_cfg, spikes)
             entry_drive = _entry_drive(params, spikes_flat, routing, cfg)
-            contrib = entry_drive * params.weights
-            currents = jax.vmap(
-                lambda c, t: jnp.zeros((n,), jnp.float32).at[t].add(c)
-            )(contrib, params.targets)
+            currents = route_currents(entry_drive, params.weights,
+                                      params.targets, n)
             hits_total = jnp.sum(entry_drive)
             addr_seq = _addr_streams(spikes, cfg, n)
             enc_per_core = jax.vmap(
@@ -328,7 +342,7 @@ def interface_tick(params, spikes: jnp.ndarray, cfg,
         element.  The tick computation is identical either way - currents
         and stats are bit-identical across telemetry modes.
     sparse_unchecked: only meaningful under ``impl="pallas_sparse"``:
-        skip the per-tick overflow ``lax.cond`` and run the fused sparse
+        skip the per-tick overflow ``lax.cond`` and run the sparse
         branch unconditionally.  Callers must have proven no core exceeds
         ``sparse_capacity`` events on any frame they will pass (the
         session's host-side precheck); see `_sparse_event_tick`.
@@ -418,10 +432,8 @@ def interface_tick(params, spikes: jnp.ndarray, cfg,
             latencies = arb.batched_tick_latency(arb_cfg, spikes)
         with jax.named_scope("repro.cam_match"):
             entry_drive = _entry_drive(params, spikes_flat, routing, cfg)
-            contrib = entry_drive * params.weights
-            currents = jax.vmap(
-                lambda c, t: jnp.zeros((n,), jnp.float32).at[t].add(c)
-            )(contrib, params.targets)
+            currents = route_currents(entry_drive, params.weights,
+                                      params.targets, n)
             hits_total = jnp.sum(entry_drive)
         with jax.named_scope("repro.aer_encode"):
             addr_seq = _addr_streams(spikes, cfg, n)

@@ -64,6 +64,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
@@ -129,8 +130,8 @@ class InterfaceSession:
       routing   CAM tags decoded to (chip, core, neuron) source addresses
                 (`RoutingIndex`) - the per-tick CAM match is a gather
                 through it (or the `cam_search` kernel when
-                ``cfg.impl == "pallas"``, or the fused
-                `repro.kernels.sparse_tick` event kernel when
+                ``cfg.impl == "pallas"``, or the
+                `repro.kernels.sparse_tick` event-buffer kernel when
                 ``cfg.impl == "pallas_sparse"``)
       cam_cycle_ns  CAM search cycle time for the configured variant
     """
@@ -210,6 +211,9 @@ class InterfaceSession:
         self._masked_sharded_cache = None
         self._sharded_tick_cache = None
         self._fault_cache = None
+        # the ("chips",) mesh the sharded tick runs on; None until it is
+        # built, and None under the single-device vmap fallback
+        self.chip_mesh = None
 
     # ---- execution -------------------------------------------------------
 
@@ -425,14 +429,13 @@ class InterfaceSession:
             acc, currents = jax.lax.scan(body, acc0, spikes_tcn)
             return currents, acc
 
-        # Donate the spikes/accumulator buffers on accelerators so the
-        # serving engine's double-buffered transfers reuse device memory;
-        # CPU would only warn (donation unimplemented), so skip it there.
-        donate = () if jax.default_backend() == "cpu" else (1, 2)
+        # No buffer donation: the bool spikes cannot alias any output,
+        # and the accumulator is a few floats per lane - while a donated
+        # accumulator would leave a retried step (`repro.serve`) holding
+        # a deleted buffer.
         mask_lane = jax.jit(lambda s, m: s & m[:, None, None])
         return {"run": jax.jit(run),
-                "run_batched": jax.jit(jax.vmap(run, in_axes=(None, 0, 0)),
-                                       donate_argnums=donate),
+                "run_batched": jax.jit(jax.vmap(run, in_axes=(None, 0, 0))),
                 "mask": jax.jit(jax.vmap(mask_lane)),
                 "mask_solo": mask_lane}
 
@@ -575,10 +578,8 @@ class InterfaceSession:
 
         def chip_body(p_chip, src_idx, active, spikes_chip, spikes_flat):
             drive = (spikes_flat[src_idx] & active).astype(jnp.float32)
-            contrib = drive * p_chip.weights
-            currents = jax.vmap(
-                lambda c, t: jnp.zeros((n,), jnp.float32).at[t].add(c)
-            )(contrib, p_chip.targets)
+            currents = pipeline.route_currents(drive, p_chip.weights,
+                                               p_chip.targets, n)
             latencies = arb.batched_tick_latency(arb_plan, spikes_chip)
             addr = pipeline._addr_streams(spikes_chip, stream_cfg, n)
             enc = jax.vmap(
@@ -617,6 +618,7 @@ class InterfaceSession:
                 cur, lat, enc, hits = body(*sq, sp_flat)
                 return cur[None], lat[None], enc[None], hits[None]
 
+            self.chip_mesh = mesh
             mapped = compat.shard_map(
                 block_body, mesh=mesh,
                 in_specs=(P("chips"), P("chips"), P("chips"), P("chips"),
@@ -692,6 +694,20 @@ class InterfaceSession:
                 "run_batched": jax.jit(jax.vmap(run, in_axes=(None, 0, 0))),
                 "mask": jax.jit(jax.vmap(mask_lane)),
                 "mask_solo": mask_lane}
+
+    def state_sharding(self, shard: str | None = None):
+        """Where state carried across calls (accumulators) should live.
+
+        One device for flat runs; replicated over the chip mesh for
+        ``shard="chips"`` runs that take the mesh, so the carry sits on
+        the same devices as the per-chip constants the sharded tick
+        closes over (a jit refuses arguments committed elsewhere).
+        """
+        if shard is not None and self.config.chips > 1:
+            self._sharded_tick()
+            if self.chip_mesh is not None:
+                return NamedSharding(self.chip_mesh, P())
+        return SingleDeviceSharding(jax.devices()[0])
 
     # ---- introspection ---------------------------------------------------
 

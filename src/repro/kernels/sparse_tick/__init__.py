@@ -1,1 +1,1 @@
-"""sparse_tick kernel package: the fused rate-proportional event tick."""
+"""sparse_tick kernel package: the sparse event tick's arbiter policies."""

@@ -1,29 +1,28 @@
-"""Fused Pallas kernel for the sparse event tick.
+"""Pallas kernel for the sparse event tick's arbiter policies.
 
-One `pallas_call` computes everything the event path needs per tick from
-the compacted address buffers: the CAM gather, the weighted scatter-add
-into synaptic currents, the arbiter ``tick_latency`` policy, and the AER
-encode energy.  Fusing the four stages keeps every intermediate - the
-(cores, entries) drive mask, the per-core address buffers - in one
-kernel's working set instead of bouncing them through HBM between four
-separately-scheduled ops.
+One `pallas_call` reads the compacted event buffer and computes both
+per-core quantities the arbiter owes the tick: the scheme's
+``tick_latency`` and its AER encode energy.  The policies are passed in
+as traceable callables (`ArbiterScheme.sparse_tick_latency` /
+``sparse_encode_energy`` factories, resolved per session), so new
+arbiter schemes reach the kernel through the registry without editing
+it.
 
-Grid and memory layout: like `repro.kernels.hat_encode`, the kernel runs
-as a single program (``grid=(1,)``) with the whole problem in VMEM and
-the core axis vectorized inside the body - per-core work at sparse-tick
-sizes (``cores x (capacity + 1)`` addresses, ``cores x entries`` CAM
-operands) is far below VMEM limits (`MAX_FUSED_ELEMS` guards the
-ceiling).  Scalar outputs are shaped ``(cores, 1)`` / ``(1, 1)`` so every
-ref stays at least 2-D.
+TPU lowering: every operand and every value in the body is 2-D - the
+buffer (cores, capacity + 1), the counts and both outputs (cores, 1) -
+because Mosaic refuses 1-D vectors.  The grid runs over blocks of
+`BLOCK_CORES` cores (the policies are row-independent), so the working
+set per program stays a few vregs whatever the core count.  The CAM
+gather and the weighted scatter-add stay in XLA
+(`repro.interface.pipeline.route_currents`): the TPU lowering has no
+vector gather over a whole frame and no scatter-add, and the XLA
+expression is the one every other path uses, which keeps the currents
+bit-identical by construction.
 
 Off TPU the kernel runs in interpret mode (`repro.kernels.cam_search`
 precedent): the body traces to the same jnp ops as
-`repro.kernels.sparse_tick.ref`, so CPU/GPU hosts execute a fused XLA
-computation with identical semantics and CI exercises the kernel path
-bit-for-bit.  The arbiter policies are passed in as traceable callables
-(`ArbiterScheme.sparse_tick_latency` / ``sparse_encode_energy``
-factories, resolved per session), so new arbiter schemes reach the
-kernel through the registry without editing it.
+`repro.kernels.sparse_tick.ref`, so CPU hosts exercise the kernel path
+bit-for-bit.
 """
 
 from __future__ import annotations
@@ -32,54 +31,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Whole-problem single-program ceiling: cores * entries operand elements.
-MAX_FUSED_ELEMS = 1 << 22
+# Cores per grid program: one sublane tile of int32.
+BLOCK_CORES = 8
 
 
-def _fused_kernel(latency_fn, encode_fn, n: int, cores: int):
-    """Bind the static config into the kernel body."""
+def _policy_kernel(latency_fn, encode_fn):
+    """Bind the resolved arbiter policies into the kernel body."""
 
-    def kernel(buf_ref, counts_ref, spikes_ref, src_ref, act_ref, w_ref,
-               tgt_ref, cur_ref, lat_ref, enc_ref, hits_ref):
+    def kernel(buf_ref, counts_ref, lat_ref, enc_ref):
         buf = buf_ref[...]
-        counts = counts_ref[...][:, 0]
-        # arbiter tick latency + AER encode energy from the event buffer
-        lat_ref[...] = latency_fn(buf, counts)[:, None]
-        enc_ref[...] = encode_fn(buf, counts)[:, None]
-        # CAM gather: is each entry's decoded source spiking this tick?
-        drive = (spikes_ref[...][src_ref[...]] & act_ref[...]).astype(
-            jnp.float32)
-        # weighted scatter-add into per-core currents (flat over cores*n;
-        # see ref.py for why this is bit-identical to the per-core form)
-        contrib = (drive * w_ref[...]).reshape(-1)
-        tgt = tgt_ref[...]
-        flat_targets = (tgt + jnp.arange(cores, dtype=tgt.dtype)[:, None] * n
-                        ).reshape(-1)
-        cur_ref[...] = jnp.zeros((cores * n,), jnp.float32).at[
-            flat_targets].add(contrib).reshape(cores, n)
-        hits_ref[...] = jnp.sum(drive)[None, None]
+        counts = counts_ref[...]
+        lat_ref[...] = latency_fn(buf, counts)
+        enc_ref[...] = encode_fn(buf, counts)
 
     return kernel
 
 
-def sparse_tick_pallas(spikes_flat, buf, counts, src_idx, active, weights,
-                       targets, *, n: int, latency_fn, encode_fn,
+def sparse_tick_pallas(buf, counts, *, latency_fn, encode_fn,
                        interpret: bool = False):
-    """Run the fused sparse tick as one `pallas_call`.
+    """Run the sparse arbiter policies as one `pallas_call`.
 
-    Same contract as `repro.kernels.sparse_tick.ref.sparse_tick_ref`
-    (see there for argument shapes and the bit-identity argument);
+    Same contract as `repro.kernels.sparse_tick.ref.sparse_tick_ref`;
     ``interpret=True`` executes the kernel body as plain XLA ops off-TPU.
     """
-    cores = buf.shape[0]
-    kernel = _fused_kernel(latency_fn, encode_fn, n, cores)
-    out_shape = [
-        jax.ShapeDtypeStruct((cores, n), jnp.float32),      # currents
-        jax.ShapeDtypeStruct((cores, 1), jnp.float32),      # latencies
-        jax.ShapeDtypeStruct((cores, 1), jnp.float32),      # encode energy
-        jax.ShapeDtypeStruct((1, 1), jnp.float32),          # CAM hits
-    ]
-    currents, lat, enc, hits = pl.pallas_call(
-        kernel, out_shape=out_shape, interpret=interpret,
-    )(buf, counts[:, None], spikes_flat, src_idx, active, weights, targets)
-    return currents, lat[:, 0], enc[:, 0], hits[0, 0]
+    cores, width = buf.shape
+    rows = BLOCK_CORES if cores % BLOCK_CORES == 0 else cores
+
+    def block(cols):
+        return pl.BlockSpec((rows, cols), lambda i: (i, 0))
+
+    col = jax.ShapeDtypeStruct((cores, 1), jnp.float32)
+    lat, enc = pl.pallas_call(
+        _policy_kernel(latency_fn, encode_fn),
+        grid=(cores // rows,),
+        in_specs=[block(width), block(1)],
+        out_specs=[block(1), block(1)],
+        out_shape=[col, col],
+        interpret=interpret,
+    )(buf, counts[:, None])
+    return lat[:, 0], enc[:, 0]

@@ -1,11 +1,10 @@
-"""Dispatch layer for the fused sparse event tick.
+"""Dispatch layer for the sparse event tick.
 
 `sparse_tick` mirrors the `repro.kernels.cam_search` /
 `repro.kernels.hat_encode` ops idiom: an ``impl`` switch between the
-plain-jnp reference (``"xla"``) and the fused Pallas kernel
-(``"pallas"``, interpret mode off-TPU by default), with shape validation
-and size guards at the dispatch boundary so kernel code never sees
-malformed operands.
+plain-jnp reference (``"xla"``) and the Pallas kernel (``"pallas"``,
+interpret mode off-TPU by default), with shape validation at the
+dispatch boundary so kernel code never sees malformed operands.
 
 Capacity policy: the per-core event buffer holds ``capacity`` live
 addresses (+1 pad slot).  `resolve_capacity` turns the user-facing
@@ -52,55 +51,37 @@ def resolve_capacity(requested: int | None, n: int) -> int:
     return max(1, min(requested, n - 1))
 
 
-def sparse_tick(spikes_flat, buf, counts, src_idx, active, weights, targets,
-                *, n: int, latency_fn, encode_fn, impl: str = "pallas",
+def sparse_tick(buf, counts, *, latency_fn, encode_fn, impl: str = "pallas",
                 interpret: bool | None = None):
-    """Fused sparse tick: CAM gather + scatter + latency + encode energy.
+    """Sparse arbiter policies: tick latency + encode energy per core.
 
     Args:
-      spikes_flat ... targets: see `ref.sparse_tick_ref`.
-      n:          neurons per core (the buffer pad value).
+      buf, counts: output of `compact_events` ((cores, K + 1) int32 and
+                  (cores,) int32).
       latency_fn: resolved ``ArbiterScheme.sparse_tick_latency(ctx)``.
       encode_fn:  resolved ``ArbiterScheme.sparse_encode_energy(ctx)``.
-      impl:       ``"pallas"`` (fused kernel) or ``"xla"`` (reference).
+      impl:       ``"pallas"`` (the kernel) or ``"xla"`` (reference).
       interpret:  force/suppress Pallas interpret mode; ``None`` picks
                   interpret automatically off-TPU.
 
     Returns:
-      (currents (cores, n) f32, latencies (cores,) f32,
-       enc_per_core (cores,) f32, hits scalar f32)
+      (latencies (cores,) f32, enc_per_core (cores,) f32)
 
     Raises:
-      ValueError: on an unknown ``impl``, mismatched operand shapes, or
-        an operand set larger than the single-program kernel supports
-        (`kernel.MAX_FUSED_ELEMS`).
+      ValueError: on an unknown ``impl`` or mismatched operand shapes.
     """
     if impl not in ("xla", "pallas"):
         raise ValueError(
             f"unknown sparse_tick impl {impl!r}; expected 'xla' or 'pallas'")
-    cores = src_idx.shape[0]
-    if buf.ndim != 2 or buf.shape[0] != cores or counts.shape != (cores,):
+    if buf.ndim != 2 or counts.shape != buf.shape[:1]:
         raise ValueError(
             f"event buffer shapes {buf.shape}/{counts.shape} do not match "
-            f"{cores} cores")
-    if spikes_flat.shape != (cores * n,):
-        raise ValueError(
-            f"spikes_flat shape {spikes_flat.shape} != ({cores * n},)")
-    if not (src_idx.shape == active.shape == weights.shape == targets.shape):
-        raise ValueError(
-            f"CAM operand shapes disagree: {src_idx.shape}, {active.shape}, "
-            f"{weights.shape}, {targets.shape}")
+            f"one count per core")
     if impl == "xla":
-        return ref.sparse_tick_ref(
-            spikes_flat, buf, counts, src_idx, active, weights, targets,
-            n=n, latency_fn=latency_fn, encode_fn=encode_fn)
-    if src_idx.size > sparse_kernel.MAX_FUSED_ELEMS:
-        raise ValueError(
-            f"fabric too large for the single-program sparse_tick kernel "
-            f"({src_idx.size} CAM operand elements > "
-            f"{sparse_kernel.MAX_FUSED_ELEMS}); use impl='xla'")
+        return ref.sparse_tick_ref(buf, counts, latency_fn=latency_fn,
+                                   encode_fn=encode_fn)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return sparse_kernel.sparse_tick_pallas(
-        spikes_flat, buf, counts, src_idx, active, weights, targets,
-        n=n, latency_fn=latency_fn, encode_fn=encode_fn, interpret=interpret)
+        buf, counts, latency_fn=latency_fn, encode_fn=encode_fn,
+        interpret=interpret)

@@ -1,4 +1,4 @@
-"""Reference implementation of the fused sparse event tick.
+"""Reference implementation of the sparse event tick.
 
 The sparse tick makes per-tick cost scale with *events* instead of
 neurons: active addresses are compacted per core into a fixed-capacity
@@ -22,18 +22,10 @@ than ``capacity`` events per core overflow; callers detect this with
 ``counts > capacity`` and fall back to the dense tick
 (`repro.interface.pipeline` wraps both in one ``lax.cond``).
 
-Bit-identity notes (the contract `tests/conformance` enforces):
-
-  * every latency/energy formula sums small integers in float32, where
-    addition is exact regardless of order, then applies the same final
-    ops (division by ``n``, ``where`` selects) as the dense path;
-  * the currents epilogue scatters ``weights * drive`` with one flat
-    scatter-add over ``cores * n`` targets.  The dense path scatters
-    per core under `jax.vmap`; both process each core's entries in
-    ascending entry order onto disjoint per-core target ranges, so every
-    output element accumulates the same values in the same order and the
-    float32 results are bit-identical (asserted, not just assumed, in
-    tests/test_sparse_tick.py).
+Bit-identity: every latency/energy formula sums small integers in
+float32, where addition is exact regardless of order, then applies the
+same final ops (division by ``n``, ``where`` selects) as the dense path
+(the contract `tests/conformance` enforces).
 """
 
 from __future__ import annotations
@@ -82,41 +74,23 @@ def event_indices(buf: jnp.ndarray, n: int):
     return ev_idx, real.reshape(-1).astype(jnp.float32)
 
 
-def sparse_tick_ref(spikes_flat, buf, counts, src_idx, active, weights,
-                    targets, *, n: int, latency_fn, encode_fn):
-    """Fused sparse tick body, plain-jnp reference for the Pallas kernel.
+def sparse_tick_ref(buf, counts, *, latency_fn, encode_fn):
+    """Sparse arbiter policies, plain-jnp reference for the Pallas kernel.
 
-    Computes the four per-tick event quantities in one place: CAM gather,
-    weighted scatter-add into currents, arbiter tick latency, and AER
-    encode energy - the work `repro.kernels.sparse_tick.kernel` fuses
-    into a single `pallas_call`.
+    Computes the two per-core quantities the arbiter owes the tick from
+    the compacted event buffer - tick latency and AER encode energy - the
+    work `repro.kernels.sparse_tick.kernel` runs as one `pallas_call`.
 
     Args:
-      spikes_flat: (cores * n,) bool flat spike frame.
       buf, counts: output of `compact_events`.
-      src_idx:     (cores, entries) int32 decoded CAM source indices
-                   (`RoutingIndex.src_idx`).
-      active:      (cores, entries) bool live-entry mask.
-      weights:     (cores, entries) float32 synaptic weights.
-      targets:     (cores, entries) int32 local target neuron per entry.
-      n:           neurons per core.
-      latency_fn:  ``(buf, counts) -> (cores,) float32`` sparse arbiter
-                   policy (`ArbiterScheme.sparse_tick_latency(ctx)`).
-      encode_fn:   ``(buf, counts) -> (cores,) float32`` sparse encode
-                   energy policy (`ArbiterScheme.sparse_encode_energy`).
+      latency_fn:  ``(buf, counts (cores, 1)) -> (cores, 1) float32``
+                   sparse arbiter policy
+                   (`ArbiterScheme.sparse_tick_latency(ctx)`).
+      encode_fn:   the same shape of policy for encode energy
+                   (`ArbiterScheme.sparse_encode_energy(ctx)`).
 
     Returns:
-      (currents (cores, n) f32, latencies (cores,) f32,
-       enc_per_core (cores,) f32, hits scalar f32)
+      (latencies (cores,) f32, enc_per_core (cores,) f32)
     """
-    cores = buf.shape[0]
-    latencies = latency_fn(buf, counts)
-    enc_per_core = encode_fn(buf, counts)
-    drive = (spikes_flat[src_idx] & active).astype(jnp.float32)
-    contrib = (drive * weights).reshape(-1)
-    flat_targets = (targets +
-                    jnp.arange(cores, dtype=targets.dtype)[:, None] * n
-                    ).reshape(-1)
-    currents = jnp.zeros((cores * n,), jnp.float32).at[flat_targets].add(
-        contrib).reshape(cores, n)
-    return currents, latencies, enc_per_core, jnp.sum(drive)
+    col = counts[:, None]
+    return latency_fn(buf, col)[:, 0], encode_fn(buf, col)[:, 0]

@@ -118,9 +118,9 @@ def snn_forward(params, topology, x_seq, cfg: SNNConfig, *, impl: str = "xla",
             v_next = v_pre * (1.0 - s)                    # reset to 0
         else:
             # fused kernel path (inference): bit-identical forward values
-            v_next, s = lif_ops.lif_step(v, current, decay=cfg.decay,
-                                         threshold=cfg.threshold, impl=impl,
-                                         interpret=True)
+            v_next, s = lif_ops.lif_step(
+                v, current, decay=cfg.decay, threshold=cfg.threshold,
+                impl=impl, interpret=jax.default_backend() != "tpu")
         return (v_next, s), s
 
     v0 = jnp.zeros((b, n), x_seq.dtype)

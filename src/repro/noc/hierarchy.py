@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from repro.core import ppa
 from repro.interface import registry as interface_registry
 from repro.noc import multicast, topology
-from repro.noc.router import NocScheme, _multicast_link_loads
+from repro.noc.router import NocScheme, _multicast_link_loads, event_sum
 
 
 class HierTables(NamedTuple):
@@ -144,7 +144,7 @@ def chip_step_costs(tables, spikes_flat: jnp.ndarray):
         return z, z, z
     ev = spikes_flat.astype(jnp.float32)
     hops = jnp.sum(ev * tables.chip_hops)
-    loads = ev @ tables.chip_link_table                        # (L_chip,)
+    loads = event_sum(ev, tables.chip_link_table)              # (L_chip,)
     depth = jnp.max(jnp.where(spikes_flat > 0, tables.chip_depth, 0))
     latency = (depth.astype(jnp.float32) * ppa.CHIP_HOP_LATENCY_NS +
                jnp.max(loads, initial=0.0) * ppa.CHIP_LINK_SERIALIZATION_NS)
@@ -165,7 +165,7 @@ def chip_step_costs_events(tables, ev_idx: jnp.ndarray, ev_w: jnp.ndarray):
         z = jnp.zeros((), jnp.float32)
         return z, z, z
     hops = jnp.sum(ev_w * tables.chip_hops[ev_idx])
-    loads = ev_w @ tables.chip_link_table[ev_idx]              # (L_chip,)
+    loads = event_sum(ev_w, tables.chip_link_table[ev_idx])
     depth = jnp.max(ev_w * tables.chip_depth[ev_idx].astype(jnp.float32))
     latency = (depth * ppa.CHIP_HOP_LATENCY_NS +
                jnp.max(loads, initial=0.0) * ppa.CHIP_LINK_SERIALIZATION_NS)

@@ -1,8 +1,8 @@
 """Mesh router model: per-link event loads, contention latency, energy.
 
 `build_tables` precomputes, from the CAM routing tables alone, everything
-the per-tick interface step needs as plain matmuls against the spike
-vector:
+the per-tick interface step needs as exact event-weighted sums against
+the spike vector:
 
   dest_counts (S,)    cores subscribed to each source  -> CAM search count
   hops        (S,)    mesh links traversed per event under the NoC scheme
@@ -76,6 +76,17 @@ class NocScheme:
     sparse_cam_accounting: Callable | None = None
 
 
+def event_sum(ev: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """``ev @ table`` as an elementwise product summed over the events.
+
+    Exact for the small integer counts the accounting carries, on every
+    backend: a TPU float32 matmul at default precision rounds its
+    operands to bfloat16, which loses integers above 256 (for example
+    ``valid_cnt @ subs`` with 512 CAM entries).
+    """
+    return jnp.sum(ev[:, None] * table, axis=0)
+
+
 def _flatten_links(h_inc: jnp.ndarray, v_inc: jnp.ndarray) -> jnp.ndarray:
     """(S, H, W-1) + (S, H-1, W) -> (S, L) in topology link order."""
     s = h_inc.shape[0]
@@ -101,10 +112,11 @@ def _unicast_link_loads(dest_mask: jnp.ndarray, src_core: jnp.ndarray,
     cols = jnp.arange(w)
 
     # dests per column / per (column, row)
-    cnt_w = m @ (dx[:, None] == cols[None, :]).astype(jnp.float32)
+    in_col = (dx[:, None] == cols[None, :]).astype(jnp.float32)  # (C, W)
+    cnt_w = jnp.sum(m[:, :, None] * in_col, axis=1)
     at = ((dx[:, None] == cols[None, :])[:, :, None] &
           (dy[:, None] == rows[None, :])[:, None, :])          # (C, W, H)
-    cnt_wy = jnp.einsum("sc,cwh->swh", m, at.astype(jnp.float32))
+    cnt_wy = jnp.sum(m[:, :, None, None] * at, axis=1)        # (S, W, H)
     pre_w = jnp.cumsum(cnt_w, axis=-1)                         # (S, W)
     tot_w = pre_w[:, -1:]
     # horizontal link j on the source row: crossed by dests right/left
@@ -203,7 +215,7 @@ def noc_step_costs(tables: NocTables, spikes_flat: jnp.ndarray):
     """
     ev = spikes_flat.astype(jnp.float32)
     hops = jnp.sum(ev * tables.hops)
-    loads = ev @ tables.link_table                             # (L,)
+    loads = event_sum(ev, tables.link_table)                   # (L,)
     depth = jnp.max(jnp.where(spikes_flat > 0, tables.depth, 0))
     latency = (depth.astype(jnp.float32) * ppa.NOC_HOP_LATENCY_NS +
                jnp.max(loads, initial=0.0) * ppa.NOC_LINK_SERIALIZATION_NS)
@@ -224,7 +236,7 @@ def noc_step_costs_events(tables: NocTables, ev_idx: jnp.ndarray,
     the dense form, so the float32 results are bit-identical.
     """
     hops = jnp.sum(ev_w * tables.hops[ev_idx])
-    loads = ev_w @ tables.link_table[ev_idx]                   # (L,)
+    loads = event_sum(ev_w, tables.link_table[ev_idx])         # (L,)
     depth = jnp.max(ev_w * tables.depth[ev_idx].astype(jnp.float32))
     latency = (depth * ppa.NOC_HOP_LATENCY_NS +
                jnp.max(loads, initial=0.0) * ppa.NOC_LINK_SERIALIZATION_NS)
@@ -264,11 +276,12 @@ def _subscribed_sparse_cam_accounting(tables, ev_idx, ev_w, valid_cnt,
                                       total_events, cores):
     """Event-indexed `_subscribed_cam_accounting` (bit-identical).
 
-    ``valid_cnt @ subs`` is the per-source swept-entry total; it depends
-    only on routing state, so XLA hoists it out of the per-tick scan.
+    ``event_sum(valid_cnt, subs)`` is the per-source swept-entry total;
+    it depends only on routing state, so XLA hoists it out of the
+    per-tick scan.
     """
     searches = jnp.sum(ev_w * tables.dest_counts[ev_idx])
-    swept = jnp.sum(ev_w * (valid_cnt @ tables.subs)[ev_idx])
+    swept = jnp.sum(ev_w * event_sum(valid_cnt, tables.subs)[ev_idx])
     entries_per_search = swept / jnp.maximum(searches, 1.0)
     return searches, entries_per_search
 

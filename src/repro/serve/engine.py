@@ -20,8 +20,8 @@ one offline ``session.run`` at a time.  The moving parts:
               ``session.run`` (currents and stats; the per-lane
               accumulator is threaded through chunks as the scan carry).
   transfer    double-buffered `jax.device_put`: chunk t+1's host->device
-              copy is issued while chunk t computes (with buffer donation
-              on accelerators, skipped on CPU).
+              copy is issued while chunk t computes.  Nothing is donated,
+              so a retried step always reads live buffers.
   metrics     per-tenant `repro.obs.metrics` histograms/counters
               (events/sec, tick-latency p50/p99, queue depth), fleet-wide
               percentiles via `Histogram.merge`, JSONL sink + records
@@ -310,17 +310,18 @@ class TenantGroup:
             return 0
         return fns["run_batched"]._cache_size()
 
-    @staticmethod
-    def _commit(tree):
-        """Place host-built accumulators on the device, committed.
+    def _commit(self, tree):
+        """Place host-built accumulators on the device(s), committed.
 
         Uncommitted numpy inputs and committed jit outputs hash to
         different fast-path cache entries; committing here keeps the
         masked batched step on ONE cache entry for the engine's lifetime
-        (the stability the soak test asserts).
+        (the stability the soak test asserts).  A ``shard="chips"`` group
+        on a real mesh keeps them replicated over the chip mesh, beside
+        the per-chip constants of its session.
         """
-        dev = jax.devices()[0]
-        return jax.tree.map(lambda x: jax.device_put(np.asarray(x), dev), tree)
+        where = self.session.state_sharding(self.shard)
+        return jax.tree.map(lambda x: jax.device_put(np.asarray(x), where), tree)
 
     def lane_names(self) -> list:
         """Tenant names in lane order (index 0 first)."""
@@ -953,8 +954,10 @@ class ServeEngine:
 
         Chunk t+1's `jax.device_put` is issued after chunk t's batched
         step is dispatched but before its results are blocked on, so the
-        host->device copy overlaps device compute; on accelerators the
-        masked jit additionally donates the spike/accumulator buffers.
+        host->device copy overlaps device compute.  No buffer is donated:
+        a retry re-reads the same chunk and the same committed
+        accumulator, which an earlier failed attempt must not have
+        consumed.
 
         Fault handling: every transfer and step runs under
         `_with_retries`; the group accumulator commits only *after* a

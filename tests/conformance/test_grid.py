@@ -2,7 +2,7 @@
 
 Fast run: every registered scenario exercises all six execution paths on
 a deterministically sampled pair of (arbiter, NoC) grid cells, plus a
-`_hypothesis_compat`-sampled oracle-vs-event sweep over the 5x3 cell
+`hypothesis`-sampled oracle-vs-event sweep over the 5x3 cell
 grid.  The full grid (every cell, every scenario) runs under ``-m slow``.
 """
 
@@ -14,7 +14,7 @@ import pytest
 
 from repro import traffic
 from repro.core import fabric
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from tests.conformance import paths
 
 TICKS = 3

@@ -3,7 +3,7 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import ppa
 from repro.core.arbiter import (Arbiter, ArbiterConfig, SCHEMES,

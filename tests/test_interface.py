@@ -3,7 +3,7 @@
 Covers the PR acceptance criteria:
   * `InterfaceSession.run` currents are bit-identical to the deprecated
     `fabric.step` for all three NoC schemes (property-style over random
-    connectivity/spike draws via `tests/_hypothesis_compat.py`),
+    connectivity/spike draws via `hypothesis`),
   * all scheme lookups go through the registries (unknown names fail with
     the registered list; new schemes plug in without touching the fabric),
   * `fabric.step` survives as a deprecated shim,
@@ -30,7 +30,7 @@ from repro.interface import (
 )
 from repro.interface import pipeline as interface_pipeline
 from repro.noc import topology
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 KEY = jax.random.PRNGKey(0)
 SCHEMES = ("broadcast", "unicast", "multicast_tree")

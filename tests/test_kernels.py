@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.cam_search import ops as cam_ops, ref as cam_ref
 from repro.kernels.hat_encode import ops as hat_ops

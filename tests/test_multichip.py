@@ -3,7 +3,7 @@
 Covers the PR acceptance criteria:
   * a ``chips=1`` config is bit-identical to the pre-existing flat-core
     path across all five arbiter schemes and all three NoC schemes
-    (property-style via `tests/_hypothesis_compat.py`),
+    (property-style via `hypothesis`),
   * currents are invariant under chip partitioning (the chip tier changes
     transport accounting, never the CAM-match semantics),
   * ``run(shard="chips")`` on a chips=4 x cores_per_chip=4 config is
@@ -28,7 +28,7 @@ from repro.core import fabric, ppa
 from repro.interface import Interface, InterfaceConfig, StepStats, ppa_report
 from repro.interface import pipeline as interface_pipeline
 from repro.noc import hierarchy, topology
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 KEY = jax.random.PRNGKey(0)
 NOC_SCHEMES = ("broadcast", "unicast", "multicast_tree")
@@ -218,6 +218,47 @@ def test_shard_map_mesh_path_matches_on_fake_devices():
                        text=True, timeout=900, env=env)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "MESH_OK" in r.stdout
+
+
+@pytest.mark.slow
+def test_sharded_serve_group_on_fake_mesh_matches_solo():
+    """A shard="chips" serving group on a real mesh (4 fake CPU devices):
+    its accumulators live beside the mesh-placed per-chip constants, and
+    served currents equal the unsharded solo runs."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = src
+    body = textwrap.dedent("""
+        import jax, numpy as np
+        assert len(jax.devices()) == 4, jax.devices()
+        from repro.interface import Interface, InterfaceConfig
+        from repro.serve import ServeEngine, TenantSpec, default_connectivity
+        cfg = InterfaceConfig(chips=4, cores_per_chip=2, neurons_per_core=16,
+                              cam_entries_per_core=32)
+        engine = ServeEngine(flush_ticks=4, flush_deadline_s=0.0,
+                             keep_currents=True)
+        specs = [TenantSpec(f"s{i}", cfg, seed=i, shard="chips")
+                 for i in range(2)]
+        for spec in specs:
+            engine.register(spec)
+            engine.submit_scenario(spec.name, 6)
+        engine.drain()
+        group = next(iter(engine.groups.values()))
+        assert group.session.chip_mesh is not None, "took the vmap fallback"
+        assert group.jit_cache_entries() == 1
+        solo = Interface(cfg).compile(default_connectivity(cfg, 0))
+        for spec in specs:
+            cur, _ = solo.run(spec.stream(6, round=0))
+            assert np.array_equal(engine.currents(spec.name), np.asarray(cur))
+        assert engine.accounting()["closes"]
+        print("SERVE_MESH_OK")
+    """)
+    r = subprocess.run([sys.executable, "-c", body], capture_output=True,
+                       text=True, timeout=900, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    assert "SERVE_MESH_OK" in r.stdout
 
 
 # ---- hierarchy tables & routing index ---------------------------------------

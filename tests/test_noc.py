@@ -10,7 +10,7 @@ import pytest
 from repro.core import cam as cam_mod
 from repro.core import fabric
 from repro.noc import multicast, placement, router, topology
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 KEY = jax.random.PRNGKey(0)
 

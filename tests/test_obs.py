@@ -32,7 +32,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import report as obs_report
 from repro.obs import telemetry as obs_telemetry
 from repro.obs import trace as obs_trace
-from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from tests.conformance.paths import ARBITER_SCHEMES, EXACT_FIELDS, GRID, NOC_SCHEMES, small_config
 
 REL = 1e-6

@@ -36,6 +36,7 @@ from repro.ft import (
     FaultModel,
     FaultPlan,
     RetriesExhaustedError,
+    TransientFaultError,
 )
 from repro.interface import Interface
 from repro.serve import (
@@ -207,6 +208,41 @@ def test_retried_faults_stay_bit_identical_to_calm_engine():
         a, b = chaotic.tenant_stats(spec.name), calm.tenant_stats(spec.name)
         for field, va in a._asdict().items():
             assert float(np.asarray(va)) == float(np.asarray(getattr(b, field)))
+    assert chaotic.accounting()["closes"]
+
+
+def test_retry_after_dispatch_reads_a_live_accumulator():
+    """An attempt that fails after its batched step was dispatched is
+    retried with the same committed accumulator; the failed attempt must
+    not have consumed it (no buffer donation), so the retry stays
+    bit-identical to an undisturbed engine."""
+    cfg = small_config("binary_tree", "multicast_tree")
+    specs = [TenantSpec("t0", cfg, scenario="sparse_poisson", seed=0)]
+    chaotic, calm = _mirrored_engines(
+        cfg, specs, retry=RetryPolicy(max_retries=2, backoff_base_s=0.0)
+    )
+    session = next(iter(chaotic.groups.values())).session
+    dispatch = session.run_batched
+    calls = []
+
+    def fails_once_after_dispatch(*args, **kw):
+        out = dispatch(*args, **kw)
+        calls.append(out)
+        if len(calls) == 1:
+            raise TransientFaultError("lost the device after dispatch")
+        return out
+
+    session.run_batched = fails_once_after_dispatch
+    for _ in range(2):
+        for engine in (chaotic, calm):
+            engine.submit_scenario("t0", TICKS)
+            engine.pump(force=True)
+    assert len(calls) == 3, "one failed attempt, then one step per round"
+    assert chaotic.registry.counter("serve.retry_recoveries").value == 1
+    assert np.array_equal(chaotic.currents("t0"), calm.currents("t0"))
+    a, b = chaotic.tenant_stats("t0"), calm.tenant_stats("t0")
+    for field, va in a._asdict().items():
+        assert float(np.asarray(va)) == float(np.asarray(getattr(b, field))), field
     assert chaotic.accounting()["closes"]
 
 

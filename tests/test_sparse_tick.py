@@ -1,4 +1,4 @@
-"""The fused sparse event tick: compaction, policies, fallback, kernel.
+"""The sparse event tick: compaction, policies, fallback, kernel.
 
 `tests/conformance` already holds the whole ``impl="pallas_sparse"``
 session bit-identical to the dense oracle across the grid; this file
@@ -22,8 +22,6 @@ from repro.interface import Interface, pipeline
 from repro.interface.config import InterfaceConfig
 from repro.interface.registry import get_arbiter
 from repro.kernels.sparse_tick import ops as sparse_ops
-from repro.kernels.sparse_tick import ref as sparse_ref
-from repro.noc import topology
 
 KEY = jax.random.PRNGKey(0)
 SPARSE_SCHEMES = ("binary_tree", "greedy_tree", "token_ring", "hier_ring",
@@ -112,10 +110,14 @@ def test_sparse_policies_match_dense(scheme):
         spikes = _frame(jax.random.PRNGKey(seed), cores=8, n=n, p=p)
         buf, counts = sparse_ops.compact_events(spikes, capacity=n - 1)
         dense_lat = arb.batched_tick_latency(cfg, spikes)
-        assert bool((lat_fn(buf, counts) == dense_lat).all()), (scheme, p)
+        lat = lat_fn(buf, counts[:, None])
+        assert lat.shape == (8, 1), lat.shape     # policies stay 2-D
+        assert bool((lat[:, 0] == dense_lat).all()), (scheme, p)
         dense_enc = jax.vmap(lambda s: arb.encode_energy_units(
             scheme, n, pipeline._hat_order(s, n)[0]))(spikes)
-        assert bool((enc_fn(buf, counts) == dense_enc).all()), (scheme, p)
+        enc = enc_fn(buf, counts[:, None])
+        assert enc.shape == (8, 1), enc.shape
+        assert bool((enc[:, 0] == dense_enc).all()), (scheme, p)
 
 
 def test_unsupported_schemes_return_none():
@@ -127,43 +129,37 @@ def test_unsupported_schemes_return_none():
     assert get_arbiter("hier_ring").sparse_tick_latency(ctx) is None
 
 
-# ---- fused tick: ref vs kernel -----------------------------------------------
+# ---- arbiter kernel: ref vs kernel -------------------------------------------
 
-def _tick_operands(cores=4, n=32, entries=64, p=0.15, scheme="hier_tree"):
+def _tick_operands(cores=4, n=32, p=0.15, scheme="hier_tree"):
     cfg = InterfaceConfig(cores=cores, neurons_per_core=n,
-                          cam_entries_per_core=entries, scheme=scheme)
-    params = fabric.random_connectivity(KEY, cfg)
-    routing = pipeline.build_routing_index(params, cfg)
+                          cam_entries_per_core=2 * n, scheme=scheme)
     spikes = _frame(jax.random.PRNGKey(5), cores, n, p)
     lat_fn, enc_fn, _, capacity = pipeline.resolve_sparse_plan(cfg)
     buf, counts = sparse_ops.compact_events(spikes, capacity)
-    return (spikes.reshape(-1), buf, counts, routing.src_idx, routing.active,
-            params.weights, params.targets), dict(
-                n=n, latency_fn=lat_fn, encode_fn=enc_fn)
+    return (buf, counts), dict(latency_fn=lat_fn, encode_fn=enc_fn)
 
 
-def test_kernel_matches_ref():
-    operands, kw = _tick_operands()
+@pytest.mark.parametrize("cores", [4, 16, 24])
+def test_kernel_matches_ref(cores):
+    # 16 and 24 cores take the multi-program grid (blocks of 8 cores)
+    operands, kw = _tick_operands(cores=cores)
     want = sparse_ops.sparse_tick(*operands, impl="xla", **kw)
     got = sparse_ops.sparse_tick(*operands, impl="pallas", interpret=True,
                                  **kw)
     for w, g in zip(want, got):
-        assert w.shape == g.shape and bool((w == g).all())
+        assert w.shape == g.shape == (cores,) and bool((w == g).all())
 
 
 def test_sparse_tick_validation():
     operands, kw = _tick_operands()
     with pytest.raises(ValueError, match="impl"):
         sparse_ops.sparse_tick(*operands, impl="cuda", **kw)
-    bad = (operands[0][:-1],) + operands[1:]
-    with pytest.raises(ValueError, match="spikes_flat"):
-        sparse_ops.sparse_tick(*bad, **kw)
-    bad = operands[:1] + (operands[1][:-1],) + operands[2:]
-    with pytest.raises(ValueError, match="cores"):
-        sparse_ops.sparse_tick(*bad, **kw)
-    bad = operands[:4] + (operands[4][:, :-1],) + operands[5:]
-    with pytest.raises(ValueError, match="disagree"):
-        sparse_ops.sparse_tick(*bad, **kw)
+    buf, counts = operands
+    with pytest.raises(ValueError, match="one count per core"):
+        sparse_ops.sparse_tick(buf[:-1], counts, **kw)
+    with pytest.raises(ValueError, match="one count per core"):
+        sparse_ops.sparse_tick(buf.reshape(-1), counts, **kw)
 
 
 # ---- overflow fallback and config plumbing -----------------------------------
@@ -247,22 +243,7 @@ def test_hat_pad_boundary_at_exact_capacity():
     assert int(counts[0]) == cap
     dense = arb.encode_energy_units(
         "hier_tree", n, pipeline._hat_order(spikes[0], n)[0])
-    assert float(enc_fn(buf, counts)[0]) == float(dense)
-
-
-def test_flat_scatter_matches_vmapped_scatter():
-    # the bit-identity claim the ref docstring makes, asserted directly
-    operands, kw = _tick_operands(p=0.3)
-    _, _, _, src_idx, active, weights, targets = operands
-    spikes_flat = operands[0]
-    drive = (spikes_flat[src_idx] & active).astype(jnp.float32)
-    contrib = drive * weights
-    n = kw["n"]
-    want = jax.vmap(
-        lambda c, t: jnp.zeros((n,), jnp.float32).at[t].add(c)
-    )(contrib, targets)
-    got = sparse_ref.sparse_tick_ref(*operands, **kw)[0]
-    assert bool((want == got).all())
+    assert float(enc_fn(buf, counts[:, None])[0, 0]) == float(dense)
 
 
 def test_default_capacity_heuristic():
