@@ -11,7 +11,9 @@ under ``"records"``) or a JSONL stream (one record per line, e.g. from
 ``stats_per_tick`` dict (the per-tick-mean `StepStats` summary) gets one
 table: latency, energy, and traffic per tier, with each tier's share of
 the summed latency.  Tick wall-clock percentiles (``tick_ms_p50/p95/p99``,
-from the benchmark's streaming histograms) are appended when present.
+from the benchmark's streaming histograms) are appended when present, and
+so are a serve record's request latencies (``request_ms_p50/p95/p99``,
+submit to commit, with ``wait_ms_p95``, submit to first packed).
 """
 
 from __future__ import annotations
@@ -102,6 +104,12 @@ def format_record(rec: dict) -> str:
         lines.append(f"  tick wall clock: {wall}")
     elif "new_tick_ms" in rec:
         lines.append(f"  tick wall clock: min {rec['new_tick_ms']:.3f} ms")
+    pcts = [(k, rec[k]) for k in ("request_ms_p50", "request_ms_p95", "request_ms_p99") if k in rec]
+    if pcts:
+        latency = "  ".join(f"{k.split('_')[-1]} {v:.3f} ms" for k, v in pcts)
+        if "wait_ms_p95" in rec:
+            latency += f"  (wait p95 {rec['wait_ms_p95']:.3f} ms)"
+        lines.append(f"  request latency: {latency}")
     faults = rec.get("faults")
     if faults:
         counts = ", ".join(f"{k} {int(v)}" for k, v in sorted(faults.items()))
@@ -120,7 +128,7 @@ def format_report(records: list, scenario: str | None = None) -> str:
     with_stats = [
         r
         for r in chosen
-        if r.get("stats_per_tick") or "new_tick_ms" in r or r.get("faults")
+        if r.get("stats_per_tick") or "new_tick_ms" in r or "request_ms_p50" in r or r.get("faults")
     ]
     if not with_stats:
         return "no reportable records" + (f" for scenario {scenario!r}" if scenario else "")

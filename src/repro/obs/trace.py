@@ -19,17 +19,26 @@ and read next to a device profile:
 
 ``trace.span(...)`` is the module-level entry point the instrumented code
 paths use (`InterfaceSession.compile`/``run``, ``benchmarks/noc_bench.py
---trace``): it records into the innermost *active* tracer, and is a
-zero-allocation no-op when none is active - instrumentation can stay in
+--trace``, the serve engine's pump): it records into the innermost
+*active* tracer, and when none is active it costs one `active_tracer`
+check and returns a shared null context - instrumentation can stay in
 library code permanently.  While a tracer is active every span also opens
 a `jax.profiler.TraceAnnotation`, so when a device profile is being
 captured (``jax.profiler.trace``) the host spans show up on its timeline
 under the same names and the two traces align.
 
-Spans nest: each event records its depth so stack-track UIs lay them out;
-`Tracer.instant` adds zero-duration marker events.  Timestamps are
-microseconds from the tracer's creation (the Chrome format's native
-unit).
+Spans nest: each event records its depth on its own thread so stack-track
+UIs lay them out; `Tracer.instant` adds zero-duration marker events and
+`Tracer.interval` an async begin/end pair (a request from submit to
+commit, say) that may overlap anything on any thread.
+
+Timestamps are microseconds (the Chrome format's native unit) since the
+Unix epoch on ``CLOCK_REALTIME`` (`time.time_ns`), the clock the profiler
+stamps its host events with: an event of the ``/host:CPU`` plane of a
+``.xplane.pb`` starts at the plane file's ``profile_start_time`` (a stat
+of its ``Task Environment`` plane) plus its ``start_ns``.  So a saved
+tracer JSON and a device profile of the same run overlay without
+shifting; ``otherData.clock`` in the JSON says so.
 """
 
 from __future__ import annotations
@@ -43,6 +52,8 @@ import time
 import jax
 
 _STACK: list = []  # innermost active tracer last; module-level by design
+_OFF = contextlib.nullcontext()  # what `span` returns while tracing is off
+CLOCK = "CLOCK_REALTIME (time.time_ns), microseconds since the Unix epoch"
 
 
 def active_tracer():
@@ -56,8 +67,7 @@ class Tracer:
     def __init__(self, process_name: str = "repro"):
         self.process_name = process_name
         self.events: list = []
-        self._origin_ns = time.perf_counter_ns()
-        self._depth = 0
+        self._local = threading.local()  # per-thread span depth
 
     # ---- activation ------------------------------------------------------
 
@@ -74,30 +84,47 @@ class Tracer:
 
     # ---- recording -------------------------------------------------------
 
-    def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._origin_ns) / 1e3
+    @staticmethod
+    def _now_us() -> float:
+        return time.time_ns() / 1e3
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
         """Record a complete event around the body (plus a jax annotation)."""
+        local = self._local
+        depth = getattr(local, "depth", 0)
+        local.depth = depth + 1
         start = self._now_us()
-        self._depth += 1
         try:
             with jax.profiler.TraceAnnotation(name):
                 yield self
         finally:
-            self._depth -= 1
+            end = self._now_us()
+            local.depth = depth
             self.events.append(
                 {
                     "name": name,
                     "ph": "X",
                     "ts": start,
-                    "dur": self._now_us() - start,
+                    "dur": end - start,
                     "pid": os.getpid(),
                     "tid": threading.get_ident(),
-                    "args": {**args, "depth": self._depth},
+                    "args": {**args, "depth": depth},
                 }
             )
+
+    def interval(self, name: str, ident: int, seconds: float, **args) -> None:
+        """An async begin/end pair that ends now and began ``seconds`` ago.
+
+        The begin is backdated, so a caller that measured the interval on
+        another clock (the serve engine's injectable one) records it when
+        it ends.  These events exist only in this tracer's JSON.
+        """
+        end = self._now_us()
+        common = {"name": name, "cat": name, "id": ident, "pid": os.getpid(),
+                  "tid": threading.get_ident()}
+        self.events.append({**common, "ph": "b", "ts": end - seconds * 1e6, "args": args})
+        self.events.append({**common, "ph": "e", "ts": end})
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event."""
@@ -126,7 +153,11 @@ class Tracer:
         }
         # ts-sorted: Perfetto tolerates disorder but diffing the JSON is nicer
         events = sorted(self.events, key=lambda e: e["ts"])
-        return {"traceEvents": [meta, *events], "displayTimeUnit": "ms"}
+        return {
+            "traceEvents": [meta, *events],
+            "displayTimeUnit": "ms",
+            "otherData": {"clock": CLOCK},
+        }
 
     def save(self, path: str) -> str:
         with open(path, "w") as f:
@@ -134,15 +165,12 @@ class Tracer:
         return path
 
 
-@contextlib.contextmanager
 def span(name: str, **args):
-    """Span on the active tracer; exact no-op when tracing is inactive."""
+    """Span on the active tracer; a shared null context when none is active."""
     tracer = active_tracer()
     if tracer is None:
-        yield None
-        return
-    with tracer.span(name, **args) as t:
-        yield t
+        return _OFF
+    return tracer.span(name, **args)
 
 
 __all__ = ["Tracer", "span", "active_tracer"]

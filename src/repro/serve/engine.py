@@ -23,9 +23,15 @@ one offline ``session.run`` at a time.  The moving parts:
               copy is issued while chunk t computes.  Nothing is donated,
               so a retried step always reads live buffers.
   metrics     per-tenant `repro.obs.metrics` histograms/counters
-              (events/sec, tick-latency p50/p99, queue depth), fleet-wide
-              percentiles via `Histogram.merge`, JSONL sink + records
-              shaped for ``python -m repro.obs.report``.
+              (events, request latency from submit to commit, and its
+              queue-and-backlog wait), fleet-wide percentiles via
+              `Histogram.merge`, JSONL sink + records shaped for
+              ``python -m repro.obs.report``.
+  tracing     while a `repro.obs.trace.Tracer` is active, every stretch
+              of a background pump thread lies in a ``serve.*`` span
+              (``serve.pump`` and its parts, or ``serve.pump.wait``) and
+              each request is an async event from submit to commit; with
+              none active each span costs one `active_tracer` check.
 
 Graceful degradation (PR 8): the engine survives a hostile environment
 instead of assuming the happy path -
@@ -99,7 +105,9 @@ to `repro.serve.lm_engine`.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import itertools
 import math
 import threading
 import time
@@ -135,14 +143,25 @@ class _Chunk:
     spikes: np.ndarray  # (capacity, flush_ticks, cores, neurons_per_core) bool
     mask: np.ndarray  # (capacity, flush_ticks) bool
     took: np.ndarray  # (capacity,) int: live ticks packed into each lane
+    parts: list  # (tenant, _Staged) of each request piece packed, lane by lane
 
 
 @dataclasses.dataclass
 class _Staged:
-    """Backlogged frames plus the submit timestamp their deadline ages from."""
+    """Backlogged frames of one request, or of a piece of it.
+
+    ``enqueued_at`` is what the shed deadline ages from and restarts on a
+    restage; ``submitted_at`` stays the request's first submit, the origin
+    of its latency.
+    """
 
     frames: np.ndarray  # (T_i, cores, neurons_per_core) bool
     enqueued_at: float
+    request_id: int
+    submitted_at: float
+    ticks: int  # of the whole request
+    taken_at: float | None = None  # when `take_chunk` first packed any of it
+    last: bool = True  # the frames end with the request's last tick
 
 
 @dataclasses.dataclass(frozen=True)
@@ -359,9 +378,11 @@ class TenantGroup:
                     f"tenant {req.tenant!r} frames shaped {frames.shape[1:]} do not match the "
                     f"group fabric ({cfg.cores}, {cfg.neurons_per_core})"
                 )
-            self._backlog[req.tenant].append(
-                _Staged(frames.astype(bool), enqueued_at=req.enqueued_at)
-            )
+            self._backlog[req.tenant].append(_Staged(
+                frames.astype(bool), enqueued_at=req.enqueued_at,
+                request_id=req.request_id, submitted_at=req.enqueued_at,
+                ticks=req.ticks,
+            ))
 
     def backlog_ticks(self) -> int:
         """Staged-but-unserved ticks across every lane of this group."""
@@ -371,13 +392,18 @@ class TenantGroup:
         """Staged-but-unserved ticks for one tenant."""
         return sum(s.frames.shape[0] for s in self._backlog[name])
 
-    def take_chunk(self, flush_ticks: int, skip=frozenset()) -> _Chunk | None:
+    def take_chunk(self, flush_ticks: int, now: float, skip=frozenset()) -> _Chunk | None:
         """Pack up to ``flush_ticks`` backlog ticks per lane, left-aligned.
 
         Shapes are fixed at (capacity, flush_ticks, ...) regardless of
         how much backlog exists, so the jitted batched step compiles once
         per capacity - partial chunks ride the mask, not a new shape, and
         free lanes stay all-False padding.
+
+        now: the engine clock's reading, stamped on each request the first
+        time any of it is packed (the end of its queue and backlog wait).
+        A request split over chunks keeps its id; only the piece holding
+        its last tick is ``last``.
 
         skip: lane names (quarantined tenants) left out of this chunk -
         their backlog is retained untouched and their mask row stays
@@ -388,6 +414,7 @@ class TenantGroup:
         took = np.zeros((b,), np.int64)
         spikes = np.zeros((b, flush_ticks, cfg.cores, cfg.neurons_per_core), bool)
         mask = np.zeros((b, flush_ticks), bool)
+        parts = []
         for name, lane in self.lanes.items():
             if name in skip:
                 continue
@@ -395,19 +422,21 @@ class TenantGroup:
             t = 0
             while queue and t < flush_ticks:
                 staged = queue.popleft()
+                if staged.taken_at is None:
+                    staged.taken_at = now
                 frames = staged.frames
                 take = min(frames.shape[0], flush_ticks - t)
                 spikes[lane, t : t + take] = frames[:take]
                 t += take
                 if take < frames.shape[0]:
-                    queue.appendleft(
-                        _Staged(frames[take:], enqueued_at=staged.enqueued_at)
-                    )
+                    queue.appendleft(dataclasses.replace(staged, frames=frames[take:]))
+                    staged = dataclasses.replace(staged, frames=frames[:take], last=False)
+                parts.append((name, staged))
             mask[lane, :t] = True
             took[lane] = t
         if not took.any():
             return None
-        return _Chunk(spikes=spikes, mask=mask, took=took)
+        return _Chunk(spikes=spikes, mask=mask, took=took, parts=parts)
 
 
 class ServeEngine:
@@ -502,6 +531,7 @@ class ServeEngine:
         self._busy_s = 0.0
         self._ticks = 0
         self._events = 0.0
+        self._request_ids = itertools.count()  # engine-wide, in submit order
         # -- threading (see class docstring for the lock order) --
         self._pump_mutex = threading.RLock()
         self._state_lock = threading.RLock()
@@ -637,7 +667,7 @@ class ServeEngine:
                 self.registry.counter("serve.rate_limited").inc()
                 self.registry.counter("serve.rate_limited_ticks").inc(ticks)
                 raise
-            group.queue.submit(tenant, frames)
+            group.queue.submit(tenant, frames, request_id=next(self._request_ids))
             self._submitted[tenant] += ticks
 
     def submit_scenario(self, tenant: str, ticks: int) -> None:
@@ -674,7 +704,7 @@ class ServeEngine:
         (foreground or background) never interleave, and `accounting()`
         never observes a chunk's ticks in flight.
         """
-        with self._pump_mutex:
+        with obs_trace.span("serve.pump"), self._pump_mutex:
             self._round += 1
             self.health.advance()
             self._faulted_this_round.clear()
@@ -682,21 +712,33 @@ class ServeEngine:
                 for ev in self.chaos.lane_faults(self._round):
                     self._lane_fault(ev)
             ticks_done = 0
-            depth_hist = self.registry.histogram("serve.queue_depth")
             for group in list(self.groups.values()):
-                with self._state_lock:
-                    depth_hist.add(group.queue.depth())
-                    group.stage(self._shed_expired(group.queue.poll(force=force)))
-                    self._shed_backlog(group)
+                with self._state_locked():
+                    with obs_trace.span("serve.stage"):
+                        group.stage(self._shed_expired(group.queue.poll(force=force)))
+                        self._shed_backlog(group)
                     skip = {n for n in group.lanes if not self.health.usable(n)}
-                    chunks = []
-                    while True:
-                        chunk = group.take_chunk(self.flush_ticks, skip=skip)
-                        if chunk is None:
-                            break
-                        chunks.append(chunk)
+                    with obs_trace.span("serve.take_chunk"):
+                        now = self.clock()
+                        chunks = []
+                        while True:
+                            chunk = group.take_chunk(self.flush_ticks, now, skip=skip)
+                            if chunk is None:
+                                break
+                            chunks.append(chunk)
                 ticks_done += self._execute(group, chunks)
             return ticks_done
+
+    @contextlib.contextmanager
+    def _state_locked(self):
+        """Hold ``_state_lock``; only the wait to acquire it is the
+        ``serve.lock_wait`` span, not the critical section."""
+        with obs_trace.span("serve.lock_wait"):
+            self._state_lock.acquire()
+        try:
+            yield
+        finally:
+            self._state_lock.release()
 
     def drain(self) -> int:
         """Serve until every queue and backlog is empty; returns ticks.
@@ -796,7 +838,8 @@ class ServeEngine:
                 self.registry.counter("serve.pump.fatal").inc()
                 return
             if served == 0:
-                self._stop_event.wait(poll_interval_s)
+                with obs_trace.span("serve.pump.wait"):
+                    self._stop_event.wait(poll_interval_s)
 
     def _raise_pump_fatal(self) -> None:
         """Re-raise a background pump thread's fatal error, chained."""
@@ -923,20 +966,18 @@ class ServeEngine:
         Called before a `RetriesExhaustedError` propagates: the ticks a
         failed chunk carried go back to ``pending``, keeping
         submitted == served + shed + pending true even across hard
-        failures (and letting a later pump serve them).  Restaged frames
-        take a fresh submit timestamp - a chunk packs frames from many
-        requests, so the original per-request ages are gone; the shed
-        deadline restarts rather than guessing.
+        failures (and letting a later pump serve them).  Each restaged
+        piece keeps its request's id, first submit and first take, so its
+        latency still runs from the submit; its shed deadline restarts
+        from now rather than charging the failed attempts to it.
         """
         now = self.clock()
         with self._state_lock:
             for chunk in reversed(chunks):
-                for name, lane in group.lanes.items():
-                    took = int(chunk.took[lane])
-                    if took:
-                        group._backlog[name].appendleft(_Staged(
-                            np.asarray(chunk.spikes[lane, :took]), enqueued_at=now
-                        ))
+                for name, piece in reversed(chunk.parts):
+                    group._backlog[name].appendleft(
+                        dataclasses.replace(piece, enqueued_at=now)
+                    )
 
     def _step(self, group: TenantGroup, spikes, mask):
         """One batched masked step (the unit a retry replays)."""
@@ -1014,11 +1055,10 @@ class ServeEngine:
     # ---- metrics ----------------------------------------------------------
 
     def _record(self, group, chunk: _Chunk, currents, acc, wall_s: float) -> None:
-        with self._state_lock:
+        with obs_trace.span("serve.record"), self._state_locked():
             self._record_locked(group, chunk, currents, acc, wall_s)
 
     def _record_locked(self, group, chunk: _Chunk, currents, acc, wall_s: float) -> None:
-        tick_ms = wall_s * 1e3 / self.flush_ticks
         fleet_events = 0.0
         events_now = np.asarray(acc.events)
         for name, lane in group.lanes.items():
@@ -1030,7 +1070,6 @@ class ServeEngine:
             self._events_seen[name] = float(events_now[lane])
             fleet_events += delta
             self.registry.counter(f"tenant.{name}.events").inc(delta)
-            self.registry.histogram(f"tenant.{name}.tick_ms").add(tick_ms)
             if name not in self._faulted_this_round:
                 # a lane that faulted *this* round doesn't get recovery
                 # credit for also serving in it - its streak must survive
@@ -1043,6 +1082,29 @@ class ServeEngine:
         self._busy_s += wall_s
         self._ticks += int(chunk.took.sum())
         self._events += fleet_events
+        self._commit_requests(chunk)
+
+    def _commit_requests(self, chunk: _Chunk) -> None:
+        """Time every request whose last tick this recorded chunk served.
+
+        ``tenant.<name>.request_ms`` takes commit - submit and
+        ``tenant.<name>.wait_ms`` first take - submit, on the engine's
+        clock; an active tracer also gets the request as an async event.
+        """
+        now = self.clock()
+        tracer = obs_trace.active_tracer()
+        for name, piece in chunk.parts:
+            if not piece.last:
+                continue
+            latency_s = now - piece.submitted_at
+            self.registry.histogram(f"tenant.{name}.request_ms").add(latency_s * 1e3)
+            self.registry.histogram(f"tenant.{name}.wait_ms").add(
+                (piece.taken_at - piece.submitted_at) * 1e3
+            )
+            if tracer is not None:
+                tracer.interval(
+                    "serve.request", piece.request_id, latency_s, tenant=name, ticks=piece.ticks
+                )
 
     def reset_metrics(self) -> None:
         """Zero served-work counters/histograms (warmup-then-measure).
@@ -1194,13 +1256,16 @@ class ServeEngine:
 
         Tenant records carry ``stats_per_tick`` (so ``python -m
         repro.obs.report`` renders the per-tier breakdown per tenant) and
-        tick-latency percentiles; the fleet record merges every tenant's
-        latency histogram (`Histogram.merge`), reports sustained
-        ``events_per_sec``, and - when any fault machinery fired - a
+        request-latency percentiles (``request_ms_p50/p95/p99``, submit to
+        commit, and ``wait_ms_p95``, submit to first packed); the fleet
+        record merges every tenant's histograms (`Histogram.merge`),
+        reports sustained ``events_per_sec`` and the step wall clock per
+        tick (``tick_ms_p*``: the watchdog's per-step histogram over
+        ``flush_ticks``), and - when any fault machinery fired - a
         ``faults`` counter dict plus recovery-time percentiles.
         """
         records = []
-        fleet_hist = None
+        fleet_hists: dict = {}
         for name in sorted(self._tenant_group):
             group = self._tenant_group[name]
             spec = group.specs[name]
@@ -1219,15 +1284,12 @@ class ServeEngine:
             }
             if spec.fault is not None:
                 rec["fault"] = spec.fault.describe()
-            hist = self.registry.histograms.get(f"tenant.{name}.tick_ms")
-            if hist is not None and hist.count:
-                summary = hist.summary()
-                rec.update(
-                    tick_ms_p50=summary["p50"],
-                    tick_ms_p95=summary["p95"],
-                    tick_ms_p99=summary["p99"],
-                )
-                fleet_hist = hist if fleet_hist is None else fleet_hist.merge(hist)
+            for kind in ("request_ms", "wait_ms"):
+                hist = self.registry.histograms.get(f"tenant.{name}.{kind}")
+                if hist is not None and hist.count:
+                    rec.update(_latency_fields(kind, hist))
+                    pooled = fleet_hists.get(kind)
+                    fleet_hists[kind] = hist if pooled is None else pooled.merge(hist)
             if served:
                 stats = self.tenant_stats(name)._asdict()
                 rec["stats_per_tick"] = {k: float(v) / served for k, v in stats.items()}
@@ -1242,13 +1304,12 @@ class ServeEngine:
             "events_per_sec": self.events_per_sec(),
             "busy_s": self._busy_s,
         }
-        if fleet_hist is not None and fleet_hist.count:
-            summary = fleet_hist.summary()
-            fleet.update(
-                tick_ms_p50=summary["p50"],
-                tick_ms_p95=summary["p95"],
-                tick_ms_p99=summary["p99"],
-            )
+        for kind, hist in fleet_hists.items():
+            fleet.update(_latency_fields(kind, hist))
+        steps = self.watchdog.registry.histograms.get(f"{self.watchdog.prefix}.step_ms")
+        if steps is not None and steps.count:
+            for q in (50, 95, 99):
+                fleet[f"tick_ms_p{q}"] = steps.percentile(q) / self.flush_ticks
         faults = self._fault_summary()
         if faults:
             fleet["faults"] = faults
@@ -1269,6 +1330,13 @@ class ServeEngine:
             for rec in records:
                 self.sink.write(rec)
         return records
+
+
+def _latency_fields(kind: str, hist: obs_metrics.Histogram) -> dict:
+    """The report's percentiles of one latency histogram: p50/p95/p99 of
+    ``request_ms``, p95 of ``wait_ms``."""
+    qs = (50, 95, 99) if kind == "request_ms" else (95,)
+    return {f"{kind}_p{q}": hist.percentile(q) for q in qs}
 
 
 def group_key(spec: TenantSpec) -> tuple:

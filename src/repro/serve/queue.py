@@ -38,7 +38,8 @@ class TickRequest:
 
     tenant: str
     frames: Any  # (T_i, cores, neurons_per_core) bool array
-    enqueued_at: float
+    enqueued_at: float  # submit time on the queue's clock
+    request_id: int = 0  # the submitter's id for it (`ServeEngine`: engine-wide)
 
     @property
     def ticks(self) -> int:
@@ -73,7 +74,7 @@ class IngestQueue:
         self._items: collections.deque = collections.deque()
         self._frames = 0
 
-    def submit(self, tenant: str, frames) -> TickRequest:
+    def submit(self, tenant: str, frames, request_id: int = 0) -> TickRequest:
         """Enqueue one validated chunk of tick frames for a tenant.
 
         Raises `FrameValidationError` on malformed frames and
@@ -82,7 +83,9 @@ class IngestQueue:
         touches the device.
         """
         frames = validate_frames(frames, shape=self.frame_shape, tenant=tenant)
-        req = TickRequest(tenant=tenant, frames=frames, enqueued_at=self.clock())
+        req = TickRequest(
+            tenant=tenant, frames=frames, enqueued_at=self.clock(), request_id=request_id
+        )
         with self._lock:
             if (
                 self.max_pending_frames is not None
@@ -106,7 +109,7 @@ class IngestQueue:
             return out
 
     def depth(self) -> int:
-        """Queued requests (the queue-depth metric the engine samples)."""
+        """Queued requests (a tenant record's ``queue_depth`` in the serve report)."""
         with self._lock:
             return len(self._items)
 

@@ -6,8 +6,8 @@ sustained operation, not in one flush:
 
 * **no queue-depth divergence**: the engine keeps up with the offered
   load round after round - queues return to empty after every drain and
-  the sampled ``serve.queue_depth`` histogram never exceeds the
-  per-round offered request count;
+  the queue depth before each drain never exceeds the per-round offered
+  request count;
 * **stable jit cache**: chunk shapes are fixed (lanes x flush_ticks), so
   the masked batched step compiles exactly once for the whole soak - a
   shape leak (recompile per round) would show up here long before it
@@ -54,9 +54,11 @@ def test_serve_soak_sustained_mixed_load():
     gc.collect()
     objects_before = len(gc.get_objects())
 
+    depth_max = 0
     for _ in range(ROUNDS - 1):
         for spec in specs:
             engine.submit_scenario(spec.name, TICKS_PER_ROUND)
+        depth_max = max(depth_max, engine.queue_depth())
         served = engine.drain()
         assert served == len(specs) * TICKS_PER_ROUND
         # no divergence: drained queues and backlogs return to empty
@@ -76,8 +78,7 @@ def test_serve_soak_sustained_mixed_load():
         assert engine.ticks_served(spec.name) == total
     assert engine.ticks_served() == len(specs) * total
     assert engine.registry.counter("serve.ticks").value == len(specs) * total
-    depth_hist = engine.registry.histograms["serve.queue_depth"]
-    assert depth_hist.max <= len(specs), "queue depth diverged beyond one round's load"
+    assert depth_max <= len(specs), "queue depth diverged beyond one round's load"
 
     records = engine.emit_report()
     fleet = records[-1]

@@ -10,7 +10,8 @@ The contract under test, in the order the layers stack:
 * ``"cores"`` per-core breakdowns sum (max, for latency) to the per-tick
   totals, and attribute inter-chip hops only when chips > 1.
 * `repro.obs.trace` spans record nested Chrome-trace events, are exact
-  no-ops when no tracer is active, and wrap session compile/run.
+  no-ops when no tracer is active, wrap session compile/run, and are
+  stamped on the clock of the profiler's host events.
 * `repro.obs.metrics` percentiles track numpy within the documented
   bucket error; the JSONL sink feeds ``python -m repro.obs.report``.
 * `StepStats.mean`/``summary(ticks=0)`` raises instead of silently
@@ -18,8 +19,12 @@ The contract under test, in the order the layers stack:
 """
 
 import dataclasses
+import glob
 import json
 import math
+import sys
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -218,6 +223,33 @@ def test_tracer_records_nested_spans(tmp_path):
     assert all(e["ph"] in ("X", "i") for e in payload["traceEvents"][1:])
 
 
+def test_tracer_keeps_each_threads_span_depth():
+    """Pump and producer threads record into one tracer: every event lands
+    and each thread's nesting depth stays its own."""
+    tracer = obs_trace.Tracer()
+
+    def work():
+        for _ in range(200):
+            with obs_trace.span("outer"):
+                with obs_trace.span("inner"):
+                    pass
+
+    threads = [threading.Thread(target=work) for _ in range(16)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with tracer:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in threads)
+    assert len(tracer.events) == 16 * 200 * 2
+    assert all(e["args"]["depth"] == (e["name"] == "inner") for e in tracer.events)
+
+
 def test_span_is_noop_without_active_tracer():
     assert obs_trace.active_tracer() is None
     with obs_trace.span("nobody-listening") as t:
@@ -232,6 +264,33 @@ def test_tracer_deactivates_on_exit():
     with obs_trace.span("after"):
         pass
     assert tracer.events == []
+
+
+def test_tracer_clock_is_the_profilers_host_clock(tmp_path):
+    """A span's start in the tracer's JSON and its annotation on the
+    profile's host plane (``profile_start_time`` + ``start_ns``) agree."""
+    from jax.profiler import ProfileData
+
+    tracer = obs_trace.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer, obs_trace.span("probe.align"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    planes = {p.name: p for p in ProfileData.from_file(path).planes}
+    origin = dict(planes["Task Environment"].stats)["profile_start_time"]
+    (start_ns,) = [
+        ev.start_ns
+        for line in planes["/host:CPU"].lines
+        for ev in line.events
+        if ev.name == "probe.align"
+    ]
+    payload = tracer.to_chrome_trace()
+    (ours,) = [e for e in payload["traceEvents"] if e["name"] == "probe.align"]
+    assert abs(ours["ts"] * 1e3 - (origin + start_ns)) < 1e6  # within 1 ms
+    assert payload["otherData"]["clock"] == obs_trace.CLOCK
 
 
 def test_session_compile_and_run_emit_spans():
@@ -446,6 +505,21 @@ def test_report_renders_tier_breakdown(tmp_path, capsys):
     shares = {tier: share for tier, _, _, _, _, share in rows}
     assert max(shares, key=shares.get) == "cam"
     assert sum(shares.values()) == pytest.approx(1.0)
+
+
+def test_report_renders_serve_request_latency(tmp_path, capsys):
+    path = tmp_path / "serve.jsonl"
+    with obs_metrics.JsonlSink(str(path)) as sink:
+        sink.write({"tenant": "t0", "ticks": 32, "request_ms_p50": 80.0,
+                    "request_ms_p95": 120.5, "request_ms_p99": 150.0, "wait_ms_p95": 70.25,
+                    "stats_per_tick": _bench_payload()["records"][0]["stats_per_tick"]})
+        sink.write({"tenant": "__fleet__", "ticks": 64, "request_ms_p50": 81.0,
+                    "request_ms_p95": 121.0, "request_ms_p99": 151.0, "wait_ms_p95": 71.0})
+    assert obs_report.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "request latency: p50 80.000 ms  p95 120.500 ms  p99 150.000 ms" in out
+    assert "(wait p95 70.250 ms)" in out
+    assert "__fleet__" in out and "p99 151.000 ms" in out
 
 
 def test_report_scenario_filter(tmp_path, capsys):

@@ -17,6 +17,9 @@ The contract under test, bottom layer first:
   serve bit-identically to their solo runs, report records carry the
   percentile + ``stats_per_tick`` fields the report CLI renders, and
   incompatible configs land on separate groups.
+* Request latency is commit - submit and its wait first take - submit, on
+  the engine's clock, through split and restaged requests; under an
+  active tracer the pump thread lies in ``serve.*`` spans throughout.
 * The LM reference loop still imports from `repro.serve.lm_engine`.
 """
 
@@ -28,9 +31,16 @@ import numpy as np
 import pytest
 
 from repro.core import fabric
-from repro.ft.chaos import RetriesExhaustedError, TransientFaultError
+from repro.ft.chaos import (
+    ChaosInjector,
+    FaultEvent,
+    FaultPlan,
+    RetriesExhaustedError,
+    TransientFaultError,
+)
 from repro.interface import Interface, InterfaceConfig, StepStats
 from repro.noc import topology
+from repro.obs import trace as obs_trace
 from repro.serve import (
     AdmissionController,
     AdmissionError,
@@ -39,6 +49,7 @@ from repro.serve import (
     CompositionError,
     IngestQueue,
     RateLimitedError,
+    RetryPolicy,
     ServeEngine,
     ServeError,
     TenantSpec,
@@ -262,18 +273,27 @@ def test_engine_report_records_and_metrics():
     engine.drain()
     records = engine.serve_report()
     assert [r["tenant"] for r in records] == ["t0", "t1", "__fleet__"]
+    latency = {"request_ms_p50", "request_ms_p95", "request_ms_p99", "wait_ms_p95"}
     for rec in records[:-1]:
         assert rec["ticks"] == 6
-        assert {"tick_ms_p50", "tick_ms_p95", "tick_ms_p99", "stats_per_tick"} <= set(rec)
+        assert latency | {"stats_per_tick"} <= set(rec)
+        assert rec["request_ms_p99"] >= rec["request_ms_p50"] > 0
         assert rec["stats_per_tick"]["events"] > 0
     fleet = records[-1]
     assert fleet["tenants"] == 2 and fleet["ticks"] == 12
     assert fleet["events_per_sec"] > 0
-    # fleet percentiles come from Histogram.merge over the tenant hists
+    # fleet request percentiles come from Histogram.merge over the tenant hists
+    assert latency <= set(fleet)
+    assert fleet["request_ms_p99"] >= fleet["request_ms_p50"] > 0
+    # the fleet's tick wall clock is the watchdog's per-step histogram
+    steps = engine.registry.histograms["serve.step_ms"]
+    assert fleet["tick_ms_p50"] == pytest.approx(steps.percentile(50) / engine.flush_ticks)
     assert fleet["tick_ms_p99"] >= fleet["tick_ms_p50"] > 0
     assert engine.registry.counter("serve.ticks").value == 12
     snapshot = engine.registry.snapshot()
-    assert "tenant.t0.tick_ms" in snapshot and "serve.queue_depth" in snapshot
+    assert "tenant.t0.request_ms" in snapshot and "tenant.t0.wait_ms" in snapshot
+    # nothing samples a per-lane tick time or the queue depth any more
+    assert "tenant.t0.tick_ms" not in snapshot and "serve.queue_depth" not in snapshot
 
 
 def test_engine_grouping_and_errors():
@@ -310,6 +330,134 @@ def test_engine_deadline_holds_partial_batches():
     engine.submit_scenario("t0", 2)
     clock.now = 1.5
     assert engine.drain() == 2  # force path ignores triggers entirely
+
+
+def _clocked_steps(engine, clock, step_s):
+    """Make every batched step of the engine's groups take ``step_s`` on
+    the fake ``clock``."""
+    for group in engine.groups.values():
+        run = group.session.run_batched
+
+        def timed(*args, _run=run, **kw):
+            clock.now += step_s
+            return _run(*args, **kw)
+
+        group.session.run_batched = timed
+
+
+def _latencies(engine, tenant):
+    """(count, min, max) of the tenant's request_ms and wait_ms histograms."""
+    hists = [engine.registry.histograms[f"tenant.{tenant}.{k}"] for k in ("request_ms", "wait_ms")]
+    return [(h.count, h.min, h.max) for h in hists]
+
+
+def test_request_latency_spans_submit_to_commit_across_chunks():
+    cfg = small_config("binary_tree", "broadcast")
+    clock = _FakeClock()
+    engine, _ = _engine(cfg, ["sparse_poisson", "mixture"], clock=clock)
+    _clocked_steps(engine, clock, 0.25)
+    engine.submit_scenario("t0", 6)  # two chunks of flush_ticks=4
+    clock.now = 0.5
+    engine.submit_scenario("t1", 3)  # one chunk
+    clock.now = 1.0
+    assert engine.pump() == 9
+    # both chunks were packed at 1.0; t1 commits with the first step (1.25),
+    # t0 only with the second (1.5), which serves its last tick
+    assert _latencies(engine, "t0") == [(1, 1500.0, 1500.0), (1, 1000.0, 1000.0)]
+    assert _latencies(engine, "t1") == [(1, 750.0, 750.0), (1, 500.0, 500.0)]
+    fleet = engine.serve_report()[-1]  # pooled: exact to a bucket's width
+    assert fleet["request_ms_p99"] == pytest.approx(1500.0, rel=0.04)
+    assert fleet["wait_ms_p95"] == pytest.approx(1000.0, rel=0.04)
+
+
+def test_restaged_request_keeps_its_id_submit_and_first_take():
+    cfg = small_config("binary_tree", "broadcast")
+    clock = _FakeClock()
+    plan = FaultPlan(events=(FaultEvent(round=2, kind="execute_fail", times=2),))
+    engine, _ = _engine(
+        cfg, ["sparse_poisson"], clock=clock, sleep=lambda s: None,
+        chaos=ChaosInjector(plan, sleep=lambda s: None),
+        retry=RetryPolicy(max_retries=1, backoff_base_s=0.0),
+        policy=AdmissionPolicy(shed_deadline_s=2.5),
+    )
+    _clocked_steps(engine, clock, 0.25)
+    engine.submit_scenario("t0", 2)  # request 0: served in round 1, by 0.25
+    assert engine.pump() == 2
+    engine.submit_scenario("t0", 4)  # request 1, submitted at 0.25
+    clock.now = 1.0
+    with pytest.raises(RetriesExhaustedError):
+        engine.pump()  # round 2: packed at 1.0, then restaged with its id
+    assert engine.accounting()["tenants"]["t0"]["pending"] == 4
+    clock.now = 3.0  # 2.75 s after submit, 2.0 s after the restage
+    tracer = obs_trace.Tracer()
+    with tracer:
+        assert engine.pump() == 4  # not shed: the deadline restarted
+    assert engine.ticks_shed("t0") == 0
+    request_ms, wait_ms = _latencies(engine, "t0")
+    assert request_ms == (2, 250.0, 3000.0)  # request 1: 3.25 - 0.25
+    assert wait_ms == (2, 0.0, 750.0)  # request 1: 1.0 - 0.25
+    begin, end = [e for e in tracer.events if e["name"] == "serve.request"]
+    assert begin["ph"] == "b" and end["ph"] == "e" and begin["id"] == end["id"] == 1
+    assert begin["args"] == {"tenant": "t0", "ticks": 4}
+    assert end["ts"] - begin["ts"] == pytest.approx(3000.0 * 1e3)
+
+
+SERVE_SPANS = {
+    "serve.pump", "serve.pump.wait", "serve.lock_wait", "serve.stage", "serve.take_chunk",
+    "serve.record", "serve.step", "serve.device_transfer",
+}
+
+
+def _serve_a_few(engine, names, rounds=4, ticks=5):
+    for _ in range(rounds):
+        for name in names:
+            engine.submit_scenario(name, ticks)
+        _await_drained(engine, names)
+        time.sleep(0.02)  # let the pump idle between bursts
+
+
+def test_traced_pump_thread_lies_in_serve_spans():
+    cfg = small_config("binary_tree", "broadcast")
+    engine, specs = _engine(cfg, ["sparse_poisson", "hotspot_core"])
+    names = [s.name for s in specs]
+    engine.submit_scenario("t0", 4)
+    engine.drain()  # compile outside the traced stretch
+    tracer = obs_trace.Tracer()
+    with tracer:
+        engine.start(poll_interval_s=0.002)
+        pump_tid = engine._pump_threads[0].ident
+        _serve_a_few(engine, names)
+        engine.stop()
+    spans = [e for e in tracer.events if e["ph"] == "X" and e["tid"] == pump_tid]
+    assert SERVE_SPANS <= {e["name"] for e in spans}
+    first = min(e["ts"] for e in spans)
+    last = max(e["ts"] + e["dur"] for e in spans)
+    outer = sorted(
+        (e["ts"], e["ts"] + e["dur"]) for e in spans if e["name"] in ("serve.pump", "serve.pump.wait")
+    )
+    covered = sum(end - start for start, end in outer)
+    assert all(a[1] <= b[0] for a, b in zip(outer, outer[1:])), "pump and wait spans overlap"
+    assert covered >= 0.95 * (last - first)
+    requests = [e for e in tracer.events if e["name"] == "serve.request"]
+    assert len(requests) == 2 * 4 * len(names)  # a begin and an end each
+    assert len({e["id"] for e in requests}) == 4 * len(names)
+
+
+def test_untraced_engine_records_no_spans_and_samples_nothing_per_pump():
+    cfg = small_config("binary_tree", "broadcast")
+    engine, specs = _engine(cfg, ["sparse_poisson", "hotspot_core"])
+    names = [s.name for s in specs]
+    idle = obs_trace.Tracer()  # made, never activated
+    engine.start(poll_interval_s=0.002)
+    _serve_a_few(engine, names)
+    engine.stop()
+    assert idle.events == []
+    per_request = {f"tenant.{n}.{k}" for n in names for k in ("request_ms", "wait_ms")}
+    assert set(engine.registry.histograms) == {"serve.step_ms"} | per_request
+    steps = engine.registry.counter("serve.flushes").value
+    assert engine.registry.histograms["serve.step_ms"].count == steps
+    for name in per_request:
+        assert engine.registry.histograms[name].count == 4
 
 
 def test_lm_engine_relocated():
