@@ -19,6 +19,9 @@ one offline ``session.run`` at a time.  The moving parts:
               mask, so every lane stays *bit-identical* to its solo
               ``session.run`` (currents and stats; the per-lane
               accumulator is threaded through chunks as the scan carry).
+              Each chunk is packed just before its step runs, so a request
+              that arrives while a pump steps rides its next step when
+              its lane has room.
   transfer    double-buffered `jax.device_put`: chunk t+1's host->device
               copy is issued while chunk t computes.  Nothing is donated,
               so a retried step always reads live buffers.
@@ -144,6 +147,7 @@ class _Chunk:
     mask: np.ndarray  # (capacity, flush_ticks) bool
     took: np.ndarray  # (capacity,) int: live ticks packed into each lane
     parts: list  # (tenant, _Staged) of each request piece packed, lane by lane
+    first_taken: int  # requests this chunk packed any of for the first time
 
 
 @dataclasses.dataclass
@@ -392,6 +396,14 @@ class TenantGroup:
         """Staged-but-unserved ticks for one tenant."""
         return sum(s.frames.shape[0] for s in self._backlog[name])
 
+    def steps_needed(self, flush_ticks: int, skip=frozenset()) -> int:
+        """Chunks the staged backlog fills: ceil of the deepest lane's
+        backlog ticks over ``flush_ticks``, lanes in ``skip`` left out."""
+        deepest = max(
+            (self.backlog_ticks_of(n) for n in self.lanes if n not in skip), default=0
+        )
+        return -(-deepest // flush_ticks)
+
     def take_chunk(self, flush_ticks: int, now: float, skip=frozenset()) -> _Chunk | None:
         """Pack up to ``flush_ticks`` backlog ticks per lane, left-aligned.
 
@@ -400,10 +412,11 @@ class TenantGroup:
         per capacity - partial chunks ride the mask, not a new shape, and
         free lanes stay all-False padding.
 
-        now: the engine clock's reading, stamped on each request the first
-        time any of it is packed (the end of its queue and backlog wait).
-        A request split over chunks keeps its id; only the piece holding
-        its last tick is ``last``.
+        now: the engine clock's reading when this chunk is packed, just
+        before its step runs; stamped on each request the first time any
+        of it is packed (the end of its queue and backlog wait).  A
+        request split over chunks keeps its id and that first stamp; only
+        the piece holding its last tick is ``last``.
 
         skip: lane names (quarantined tenants) left out of this chunk -
         their backlog is retained untouched and their mask row stays
@@ -415,6 +428,7 @@ class TenantGroup:
         spikes = np.zeros((b, flush_ticks, cfg.cores, cfg.neurons_per_core), bool)
         mask = np.zeros((b, flush_ticks), bool)
         parts = []
+        first_taken = 0
         for name, lane in self.lanes.items():
             if name in skip:
                 continue
@@ -424,6 +438,7 @@ class TenantGroup:
                 staged = queue.popleft()
                 if staged.taken_at is None:
                     staged.taken_at = now
+                    first_taken += 1
                 frames = staged.frames
                 take = min(frames.shape[0], flush_ticks - t)
                 spikes[lane, t : t + take] = frames[:take]
@@ -436,7 +451,8 @@ class TenantGroup:
             took[lane] = t
         if not took.any():
             return None
-        return _Chunk(spikes=spikes, mask=mask, took=took, parts=parts)
+        return _Chunk(spikes=spikes, mask=mask, took=took, parts=parts,
+                      first_taken=first_taken)
 
 
 class ServeEngine:
@@ -476,12 +492,17 @@ class ServeEngine:
     concurrent with a background pump.  Two locks, always taken in this
     order:
 
-      _pump_mutex   serializes whole pump iterations (and accounting /
+      _pump_mutex   serializes whole pump rounds (and accounting /
                     register / deregister against them), so the ledger
-                    is never observed with a chunk's ticks in flight.
+                    is never observed with a chunk's ticks in flight.  A
+                    round steps a group at most as many times as the
+                    backlog it staged first needs, so these callers wait
+                    a bounded time even while producers keep submitting.
       _state_lock   guards the ledger dicts, queue polls, and backlog
-                    mutation; `submit` takes only this one, so producers
-                    never block behind a full pump iteration.
+                    mutation; the pump takes it between the steps of a
+                    round to stage new arrivals and pack the next chunk.
+                    `submit` takes only this one, so producers never
+                    block behind a pump round.
     """
 
     def __init__(
@@ -689,18 +710,23 @@ class ServeEngine:
     # ---- serving loop -----------------------------------------------------
 
     def pump(self, force: bool = False) -> int:
-        """One engine iteration: flush ready queues, step their groups.
+        """One engine round: flush ready queues, step their groups.
 
         Returns the number of live ticks served.  ``force`` flushes
         regardless of the micro-batch triggers (drain semantics).
 
         Each pump is one *round* of the chaos clock: quarantine cooldowns
         age first, then this round's scheduled lane faults land, then
-        expired requests are shed (from the queue *and* the staged
-        backlog), and finally every group steps with its quarantined
-        lanes masked out.
+        each group's queue is polled, expired requests are shed (from the
+        queue *and* the staged backlog) and the rest staged, and the
+        group steps with its quarantined lanes masked out.  Only the
+        first chunk is packed here; `_execute` packs each later one just
+        before it runs, from the backlog plus whatever reached the queue
+        meanwhile.  A round steps a group at most N times, N the steps
+        its deepest usable lane's staged backlog needed when the round
+        began, so a producer that keeps submitting cannot hold a pump.
 
-        Thread-safe: the whole iteration holds ``_pump_mutex``, so pumps
+        Thread-safe: the whole round holds ``_pump_mutex``, so pumps
         (foreground or background) never interleave, and `accounting()`
         never observes a chunk's ticks in flight.
         """
@@ -714,20 +740,29 @@ class ServeEngine:
             ticks_done = 0
             for group in list(self.groups.values()):
                 with self._state_locked():
-                    with obs_trace.span("serve.stage"):
-                        group.stage(self._shed_expired(group.queue.poll(force=force)))
-                        self._shed_backlog(group)
+                    self._stage(group, force)
                     skip = {n for n in group.lanes if not self.health.usable(n)}
-                    with obs_trace.span("serve.take_chunk"):
-                        now = self.clock()
-                        chunks = []
-                        while True:
-                            chunk = group.take_chunk(self.flush_ticks, now, skip=skip)
-                            if chunk is None:
-                                break
-                            chunks.append(chunk)
-                ticks_done += self._execute(group, chunks)
+                    steps = group.steps_needed(self.flush_ticks, skip)
+                    chunk = self._take(group, skip)
+                ticks_done += self._execute(group, chunk, steps, skip, force)
             return ticks_done
+
+    def _stage(self, group: TenantGroup, force: bool) -> int:
+        """Poll the group's queue, shed what expired, stage the rest and
+        shed the backlog (``_state_lock`` held); returns requests staged."""
+        with obs_trace.span("serve.stage"):
+            requests = self._shed_expired(group.queue.poll(force=force))
+            group.stage(requests)
+            self._shed_backlog(group)
+        return len(requests)
+
+    def _take(self, group: TenantGroup, skip) -> _Chunk | None:
+        """Pack the group's next chunk now (``_state_lock`` held)."""
+        with obs_trace.span("serve.take_chunk"):
+            chunk = group.take_chunk(self.flush_ticks, self.clock(), skip=skip)
+        if chunk is not None and chunk.first_taken:
+            self.registry.counter("serve.packed_requests").inc(chunk.first_taken)
+        return chunk
 
     @contextlib.contextmanager
     def _state_locked(self):
@@ -960,10 +995,10 @@ class ServeEngine:
             return out
         raise AssertionError("unreachable")  # loop always returns or raises
 
-    def _restage(self, group: TenantGroup, chunks: list) -> None:
-        """Return unserved chunks to the front of the backlog, in order.
+    def _restage(self, group: TenantGroup, chunk: _Chunk) -> None:
+        """Return an unserved chunk to the front of the backlog, in order.
 
-        Called before a `RetriesExhaustedError` propagates: the ticks a
+        Called before a `RetriesExhaustedError` propagates: the ticks the
         failed chunk carried go back to ``pending``, keeping
         submitted == served + shed + pending true even across hard
         failures (and letting a later pump serve them).  Each restaged
@@ -973,11 +1008,8 @@ class ServeEngine:
         """
         now = self.clock()
         with self._state_lock:
-            for chunk in reversed(chunks):
-                for name, piece in reversed(chunk.parts):
-                    group._backlog[name].appendleft(
-                        dataclasses.replace(piece, enqueued_at=now)
-                    )
+            for name, piece in reversed(chunk.parts):
+                group._backlog[name].appendleft(dataclasses.replace(piece, enqueued_at=now))
 
     def _step(self, group: TenantGroup, spikes, mask):
         """One batched masked step (the unit a retry replays)."""
@@ -990,46 +1022,59 @@ class ServeEngine:
             spikes, mask=mask, stats0=group.lane_stats(), shard=group.shard, **kw
         )
 
-    def _execute(self, group: TenantGroup, chunks: list) -> int:
-        """Step one group through its chunks with double-buffered transfer.
+    def _execute(self, group: TenantGroup, chunk: _Chunk | None, steps: int,
+                 skip, force: bool) -> int:
+        """Step one group up to ``steps`` times, packing as it goes.
 
-        Chunk t+1's `jax.device_put` is issued after chunk t's batched
-        step is dispatched but before its results are blocked on, so the
-        host->device copy overlaps device compute.  No buffer is donated:
-        a retry re-reads the same chunk and the same committed
-        accumulator, which an earlier failed attempt must not have
-        consumed.
+        ``chunk`` is the round's first chunk.  Once step i is dispatched,
+        and before its results are blocked on, the queue is polled and
+        staged again and chunk i+1 is packed from the backlog as it then
+        stands, so a request that arrived during step i rides step i+1
+        when its lane has room; chunk i+1's `jax.device_put` follows, so
+        the packing and the host->device copy overlap device compute.
+        The round ends after ``steps`` steps or at the first empty chunk.
+        No buffer is donated: a retry re-reads the same chunk and the
+        same committed accumulator, which an earlier failed attempt must
+        not have consumed.
 
         Fault handling: every transfer and step runs under
         `_with_retries`; the group accumulator commits only *after* a
         successful step (a replayed chunk can never double-count), and on
-        `RetriesExhaustedError` the unserved chunks are restaged before
-        the error propagates.
+        `RetriesExhaustedError` the chunk that failed is restaged before
+        the error propagates: a failed step's own chunk (no look-ahead is
+        packed before a step is dispatched), or a failed look-ahead
+        transfer's chunk once the step before it is recorded.
         """
-        if not chunks:
+        if chunk is None:
             return 0
         ticks_done = 0
         try:
-            staged = self._with_retries("transfer", lambda: self._transfer(chunks[0]))
+            buffers = self._with_retries("transfer", lambda: self._transfer(chunk))
         except RetriesExhaustedError:
-            self._restage(group, chunks)
+            self._restage(group, chunk)
             raise
-        for i, chunk in enumerate(chunks):
-            spikes, mask = staged
+        for i in range(steps):
+            spikes, mask = buffers
             t0 = self.clock()
-            transfer_err = None
+            nxt = transfer_err = None
             with obs_trace.span("serve.step", lanes=len(group.lanes)):
                 try:
                     currents, acc = self._with_retries(
                         "execute", lambda: self._step(group, spikes, mask)
                     )
                 except RetriesExhaustedError:
-                    self._restage(group, chunks[i:])
+                    self._restage(group, chunk)
                     raise
-                if i + 1 < len(chunks):
+                if i + 1 < steps:
+                    with self._state_locked():
+                        joined = self._stage(group, force)
+                        nxt = self._take(group, skip)
+                    if joined:
+                        self.registry.counter("serve.midpump_requests").inc(joined)
+                if nxt is not None:
                     try:
-                        staged = self._with_retries(
-                            "transfer", lambda: self._transfer(chunks[i + 1])
+                        buffers = self._with_retries(
+                            "transfer", lambda: self._transfer(nxt)
                         )
                     except RetriesExhaustedError as e:
                         transfer_err = e
@@ -1041,9 +1086,12 @@ class ServeEngine:
             self._record(group, chunk, currents, acc, wall_s)
             ticks_done += int(chunk.took.sum())
             if transfer_err is not None:
-                # chunk i is fully recorded; only i+1.. go back to pending
-                self._restage(group, chunks[i + 1 :])
+                # this chunk is fully recorded; only the look-ahead goes back
+                self._restage(group, nxt)
                 raise transfer_err
+            if nxt is None:
+                break
+            chunk = nxt
         return ticks_done
 
     def _transfer(self, chunk: _Chunk):
@@ -1259,9 +1307,11 @@ class ServeEngine:
         request-latency percentiles (``request_ms_p50/p95/p99``, submit to
         commit, and ``wait_ms_p95``, submit to first packed); the fleet
         record merges every tenant's histograms (`Histogram.merge`),
-        reports sustained ``events_per_sec`` and the step wall clock per
+        reports sustained ``events_per_sec``, the step wall clock per
         tick (``tick_ms_p*``: the watchdog's per-step histogram over
-        ``flush_ticks``), and - when any fault machinery fired - a
+        ``flush_ticks``), the requests packed and how many of them were
+        staged between two steps of a pump (``midpump_requests``, and
+        their ``midpump_share``), and - when any fault machinery fired - a
         ``faults`` counter dict plus recovery-time percentiles.
         """
         records = []
@@ -1306,6 +1356,12 @@ class ServeEngine:
         }
         for kind, hist in fleet_hists.items():
             fleet.update(_latency_fields(kind, hist))
+        packed = self.registry.counters.get("serve.packed_requests")
+        if packed is not None and packed.value:
+            joined = self.registry.counters.get("serve.midpump_requests")
+            fleet["packed_requests"] = int(packed.value)
+            fleet["midpump_requests"] = int(joined.value) if joined is not None else 0
+            fleet["midpump_share"] = fleet["midpump_requests"] / fleet["packed_requests"]
         steps = self.watchdog.registry.histograms.get(f"{self.watchdog.prefix}.step_ms")
         if steps is not None and steps.count:
             for q in (50, 95, 99):

@@ -402,6 +402,93 @@ def test_restaged_request_keeps_its_id_submit_and_first_take():
     assert end["ts"] - begin["ts"] == pytest.approx(3000.0 * 1e3)
 
 
+def _arrivals_during_steps(engine, arrivals):
+    """Wrap the engine's batched step: while step k (from 1) runs, submit
+    each ``(tenant, ticks)`` of ``arrivals(k)``.  Returns the list that
+    collects each step's lanes with live ticks."""
+    step, lanes = engine._step, []
+
+    def stepping(group, spikes, mask):
+        lanes.append(np.flatnonzero(np.asarray(mask).any(axis=1)).tolist())
+        for name, ticks in arrivals(len(lanes)):
+            engine.submit_scenario(name, ticks)
+        return step(group, spikes, mask)
+
+    engine._step = stepping
+    return lanes
+
+
+def test_midpump_arrival_rides_the_next_step_of_the_pump_in_flight():
+    cfg = small_config("binary_tree", "broadcast")
+    engine, specs = _engine(cfg, ["sparse_poisson", "hotspot_core"], keep_currents=True)
+    engine.submit_scenario("t0", 12)  # three steps of flush_ticks=4
+    lanes = _arrivals_during_steps(engine, lambda k: [("t1", 4)] if k == 1 else [])
+    assert engine.pump(force=True) == 16
+    # t1's lane was empty: its request joined step 2, before t0's last chunk
+    assert lanes == [[0], [0, 1], [0]]
+    assert engine.ticks_served("t1") == 4
+    session = _session(cfg)
+    for spec, t in zip(specs, (12, 4)):
+        cur_solo, acc_solo = session.run(spec.stream(t, round=0))
+        assert np.array_equal(engine.currents(spec.name), np.asarray(cur_solo)), spec.name
+        _assert_stats_equal(engine.tenant_stats(spec.name), acc_solo, spec.name)
+
+
+def test_pump_without_arrivals_steps_its_backlog_once_bit_identically():
+    cfg = small_config("binary_tree", "multicast_tree")
+    engine, specs = _engine(
+        cfg, ["sparse_poisson", "hotspot_core", "synchronized_burst"], keep_currents=True
+    )
+    streams = {"t0": (5, 6), "t1": (4,), "t2": (7,)}  # t0's 11 ticks need 3 steps
+    for name, lengths in streams.items():
+        for t in lengths:
+            engine.submit_scenario(name, t)
+    assert engine.pump(force=True) == 22
+    assert engine.registry.counter("serve.flushes").value == 3
+    assert engine.registry.counter("serve.packed_requests").value == 4
+    assert "serve.midpump_requests" not in engine.registry.counters
+    session = _session(cfg)
+    for spec in specs:
+        stream = np.concatenate(
+            [np.asarray(spec.stream(t, round=r)) for r, t in enumerate(streams[spec.name])]
+        )
+        cur_solo, acc_solo = session.run(stream)
+        assert np.array_equal(engine.currents(spec.name), np.asarray(cur_solo)), spec.name
+        _assert_stats_equal(engine.tenant_stats(spec.name), acc_solo, spec.name)
+
+
+def test_a_steady_producer_cannot_hold_a_pump_past_its_backlog():
+    cfg = small_config("binary_tree", "broadcast")
+    engine, _ = _engine(cfg, ["sparse_poisson", "hotspot_core"])
+    engine.submit_scenario("t0", 8)  # the round starts with two steps of backlog
+    lanes = _arrivals_during_steps(engine, lambda k: [("t0", 4), ("t1", 4)])
+    assert engine.pump(force=True) == 12
+    assert len(lanes) == 2 and lanes[1] == [0, 1]
+    assert engine.accounting()["closes"]
+    del engine._step  # the producer stops
+    engine.drain()
+    acct = engine.accounting()
+    assert acct["closes"] and all(v["pending"] == 0 for v in acct["tenants"].values())
+
+
+def test_midpump_counter_counts_the_requests_that_joined_in_flight():
+    cfg = small_config("binary_tree", "broadcast")
+    engine, _ = _engine(cfg, ["sparse_poisson", "hotspot_core", "mixture"])
+    engine.submit_scenario("t0", 12)  # three steps
+    plan = {1: [("t1", 4), ("t2", 2)], 2: [("t1", 3)], 3: [("t2", 1)]}
+    _arrivals_during_steps(engine, lambda k: plan.get(k, []))
+    assert engine.pump(force=True) == 12 + 4 + 2 + 3
+    counters = engine.registry.counters
+    # the arrival during the last step waits for the next round's prologue
+    assert counters["serve.midpump_requests"].value == 3
+    assert counters["serve.packed_requests"].value == 4
+    assert engine.drain() == 1
+    assert counters["serve.midpump_requests"].value == 3
+    fleet = engine.serve_report()[-1]
+    assert fleet["packed_requests"] == 5 and fleet["midpump_requests"] == 3
+    assert fleet["midpump_share"] == pytest.approx(0.6)
+
+
 SERVE_SPANS = {
     "serve.pump", "serve.pump.wait", "serve.lock_wait", "serve.stage", "serve.take_chunk",
     "serve.record", "serve.step", "serve.device_transfer",

@@ -246,6 +246,69 @@ def test_retry_after_dispatch_reads_a_live_accumulator():
     assert chaotic.accounting()["closes"]
 
 
+@pytest.mark.parametrize("kind", ["transfer", "execute"])
+def test_failed_lookahead_restages_a_midpump_request_in_order(kind):
+    """A request that joined a pump in flight rides the look-ahead chunk;
+    when that chunk's transfer or step fails for good, its pieces go back
+    to the front of their lanes, in order, keeping id, submit and first
+    take, and a later drain serves every lane bit-identically to solo."""
+    cfg = small_config("binary_tree", "multicast_tree")
+    clock = _FakeClock()
+    specs = [
+        TenantSpec("t0", cfg, scenario="sparse_poisson", seed=0),
+        TenantSpec("t1", cfg, scenario="hotspot_core", seed=1),
+    ]
+    engine = _engine(
+        keep_currents=True, clock=clock, retry=RetryPolicy(max_retries=1, backoff_base_s=0.0)
+    )
+    for spec in specs:
+        engine.register(spec)
+    engine.submit_scenario("t0", 12)  # request 0
+    engine.submit_scenario("t0", TICKS)  # request 1: t0 holds 20 ticks, 3 steps
+    step, transfer, steps = engine._step, engine._transfer, []
+
+    def stepping(group, spikes, mask):
+        steps.append(1)
+        clock.now += 0.25
+        if len(steps) == 1:
+            engine.submit_scenario("t1", TICKS)  # request 2 joins in flight
+        elif kind == "execute":
+            raise TransientFaultError("the look-ahead step failed")
+        return step(group, spikes, mask)
+
+    def transferring(chunk):
+        if kind == "transfer" and steps:
+            raise TransientFaultError("the look-ahead transfer failed")
+        return transfer(chunk)
+
+    engine._step, engine._transfer = stepping, transferring
+    with pytest.raises(RetriesExhaustedError):
+        engine.pump(force=True)
+    assert engine.ticks_served("t0") == TICKS and engine.ticks_served("t1") == 0
+    acct = engine.accounting()
+    assert acct["closes"]
+    assert acct["tenants"]["t0"]["pending"] == 12 and acct["tenants"]["t1"]["pending"] == TICKS
+    backlog = next(iter(engine.groups.values()))._backlog
+    t0 = [(p.request_id, p.frames.shape[0], p.last) for p in backlog["t0"]]
+    assert t0 == [(0, 4, True), (1, 4, False), (1, 4, True)]
+    (joined,) = backlog["t1"]
+    assert (joined.request_id, joined.submitted_at, joined.taken_at) == (2, 0.25, 0.25)
+    assert joined.enqueued_at == clock.now  # only the shed deadline restarts
+    engine._step, engine._transfer = step, transfer
+    engine.drain()
+    assert engine.accounting()["closes"]
+    request_ms = engine.registry.histograms["tenant.t1.request_ms"]
+    assert request_ms.count == 1 and request_ms.min == pytest.approx(
+        (clock.now - 0.25) * 1e3
+    )
+    params = default_connectivity(cfg, 0)
+    solo = Interface(cfg).compile(params)
+    for spec, lengths in zip(specs, ((12, TICKS), (TICKS,))):
+        stream = jnp.concatenate([spec.stream(t, round=r) for r, t in enumerate(lengths)])
+        cur, _ = solo.run(stream)
+        assert np.array_equal(engine.currents(spec.name), np.asarray(cur)), spec.name
+
+
 def test_retries_exhausted_restages_then_recovers():
     cfg = small_config("binary_tree", "broadcast")
     plan = FaultPlan(events=(FaultEvent(round=1, kind="transfer_fail", times=6),))
