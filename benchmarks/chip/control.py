@@ -22,49 +22,11 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
 def readings(cell, seed, seconds) -> dict:
-    import jax
-    import numpy as np
+    """The control's readings of one seed: the cell's driver makes them
+    (``drivers/<driver>.py``'s ``control_readings``)."""
+    from chip import harness
 
-    from chip import compare, data
-    from chip.drivers import fleet, offline
-
-    mix, config = cell.mix, cell.config
-    fab = config["fabric"]
-    _, conn = data.connectivity(data.seed_key(seed, 0), config)
-    if mix["driver"] == "offline":
-        make = data.raster_fn(mix["generator"], mix["params"], mix["ticks"],
-                              fab)
-        hosts = [np.asarray(make(jax.random.split(
-            data.seed_key(seed, 1 + b), mix["lanes"])))
-            for b in range(mix["batches"])]
-        rng = np.random.default_rng(seed)
-        rng.integers(1, mix["sample_calls_from"])
-        sample = np.sort(rng.choice(mix["lanes"], mix["sample_lanes"],
-                                    replace=False))
-        ref_rows, ref_cur = offline.expected(config, conn, hosts, sample)
-        ctl_rows, ctl_cur = offline.expected(config, conn, hosts, sample,
-                                             control=True)
-        out = compare.stats_gaps(np.concatenate(ctl_rows),
-                                 np.concatenate(ref_rows))
-        out["currents"] = max(compare.currents_gap(c, r)
-                              for cs, rs in zip(ctl_cur, ref_cur)
-                              for c, r in zip(cs, rs))
-        return out
-    make = data.raster_fn(mix["generator"], mix["params"],
-                          mix["request_ticks"], fab)
-    pool = np.asarray(make(jax.random.split(data.seed_key(seed, 1),
-                                            mix["pool"])))
-    picks = [list(p) for p in zip(*fleet.warmup_picks(mix))]
-    rng = np.random.default_rng(seed)
-    _, tenant, pick = fleet.plan(rng, mix["rate_per_s"], seconds,
-                                 mix["tenants"], mix["pool"])
-    for t, p in zip(tenant, pick):
-        picks[t].append(int(p))
-    ref = fleet.tenant_totals(config, conn, pool, picks)
-    ctl = fleet.tenant_totals(config, conn, pool, picks, control=True)
-    out = compare.stats_gaps(ctl, ref)
-    out["unserved"] = 0.0
-    return out
+    return harness.driver(cell).control_readings(cell, seed, seconds)
 
 
 def main(argv=None) -> int:

@@ -39,6 +39,12 @@ from chip.generators import arrivals
 from chip.reference import Reference, accumulate
 
 
+def shrink(mix: dict) -> None:
+    """The mix, in place, at a size a CPU test run can hold."""
+    mix.update(tenants=4, pool=16, rate_per_s=200, request_ticks=8,
+               flush_ticks=8, trace_seconds=0.5)
+
+
 def plan(rng, rate, seconds, tenants, pool):
     """``(arrival times, tenant index, pool index)`` of a window's requests."""
     n = max(1, int(round(rate * seconds)))
@@ -76,6 +82,31 @@ def warmup_picks(mix):
     t = mix["tenants"]
     return [[(r * t + j) % mix["pool"] for j in range(t)]
             for r in range(mix["warmup_rounds"])]
+
+
+def control_readings(cell, seed, seconds):
+    """The numbers the check compares, with the reference in bfloat16 in
+    the program's place, over the request sequence a run of ``seed`` with
+    a ``seconds`` window would serve."""
+    import jax
+
+    mix, config = cell.mix, cell.config
+    _, conn = data.connectivity(data.seed_key(seed, 0), config)
+    make = data.raster_fn(mix["generator"], mix["params"],
+                          mix["request_ticks"], config["fabric"])
+    pool = np.asarray(make(jax.random.split(data.seed_key(seed, 1),
+                                            mix["pool"])))
+    picks = [list(p) for p in zip(*warmup_picks(mix))]
+    rng = np.random.default_rng(seed)
+    _, tenant, pick = plan(rng, mix["rate_per_s"], seconds, mix["tenants"],
+                           mix["pool"])
+    for t, p in zip(tenant, pick):
+        picks[t].append(int(p))
+    ref = tenant_totals(config, conn, pool, picks)
+    ctl = tenant_totals(config, conn, pool, picks, control=True)
+    out = compare.stats_gaps(ctl, ref)
+    out["unserved"] = 0.0
+    return out
 
 
 class Fleet:

@@ -60,17 +60,68 @@ class Record:
     trace: dict | None = None     # `trace_reduce.reduce` of the window
 
 
+# Keys of a configuration's ``fabric`` that are its shapes: never cut.
+WIDTHS = ("cores_per_chip", "neurons_per_core", "cam_entries_per_core",
+          "scheme", "noc", "cam")
+
+
+def cut_faults(entry: dict, config: dict) -> list:
+    """Why a configuration file's stated cut is not sound; [] if it is.
+
+    ``entry`` is the configuration's entry in ``BENCHMARK.json``.  The
+    file's ``reduced`` has to equal the entry's, every key in it has to be
+    a key of the file's ``fabric`` that is not a width (`WIDTHS`), and the
+    file's ``published`` has to give each such key's published value,
+    which differs from the one run.
+    """
+    reduced = config.get("reduced")
+    if reduced != entry["reduced"]:
+        return [f"{config['name']}: the file's reduced {reduced!r} is not "
+                f"BENCHMARK.json's {entry['reduced']!r}"]
+    fab, published = config["fabric"], config.get("published", {})
+    faults = []
+    for key in reduced:
+        if key not in fab:
+            faults.append(f"{key!r} is not a key of the fabric")
+        elif key in WIDTHS:
+            faults.append(f"{key!r} is a width and is never cut")
+        elif key not in published:
+            faults.append(f"published gives no value of {key!r}")
+        elif published[key] == fab[key]:
+            faults.append(f"{key!r} is run at its published value "
+                          f"{fab[key]!r}")
+    return [f"{config['name']}: {f}" for f in faults]
+
+
 def load_cell(name: str) -> Cell:
+    """The cell ``name`` of ``BENCHMARK.json``, with its files read.
+
+    Raises ValueError where its configuration states an unsound cut.
+    """
     bench = load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json; known: "
                        f"{', '.join(cells)}")
     w = cells[name]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=load_json(HERE, "configs", w["config"] + ".json"),
+    config = load_json(HERE, "configs", w["config"] + ".json")
+    entry = next(c for c in bench["configs"] if c["name"] == w["config"])
+    faults = cut_faults(entry, config)
+    if faults:
+        raise ValueError("; ".join(faults))
+    return Cell(name=name, chips=int(w["chips"]), config=config,
                 mix=load_json(HERE, "traffic", w["traffic"] + ".json"),
                 limits=load_json(HERE, "limits", name + ".json"))
+
+
+def driver(cell: Cell):
+    """The module ``drivers/<driver>.py`` that the cell's mix names.
+
+    A driver has ``run`` (one run of the cell), ``shrink`` (the mix at a
+    size a CPU test run can hold) and ``control_readings`` (the check's
+    control at the cell's own sizes).
+    """
+    return importlib.import_module("chip.drivers." + cell.mix["driver"])
 
 
 def metrics_of(name: str, kind: str) -> list:
@@ -167,14 +218,14 @@ def result(cell: Cell, record: Record, trace: bool, device: dict) -> dict:
 def drive(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
           counter: CompileCounter | None = None) -> Record:
     """Run the cell's driver once; trace its window when asked."""
-    driver = importlib.import_module("chip.drivers." + cell.mix["driver"])
+    run = driver(cell).run
     if not trace:
-        return driver.run(cell, seed, seconds, t0, counter=counter)
+        return run(cell, seed, seconds, t0, counter=counter)
     from chip import trace_reduce
 
     with tempfile.TemporaryDirectory(prefix="chipbench-trace-") as tdir:
-        record = driver.run(cell, seed, seconds, t0, counter=counter,
-                            trace_dir=tdir)
+        record = run(cell, seed, seconds, t0, counter=counter,
+                     trace_dir=tdir)
         record.trace = trace_reduce.reduce(trace_reduce.find_xplane(tdir))
     return record
 

@@ -72,12 +72,16 @@ def test_every_file_of_a_cell_is_found_by_name(name):
     assert cell.config["name"] == next(
         w["config"] for w in BENCH["workloads"] if w["name"] == name)
     generators.load(cell.mix["generator"])
-    __import__("chip.drivers." + cell.mix["driver"])
+    drv = harness.driver(cell)
+    assert all(callable(getattr(drv, f, None))
+               for f in ("run", "shrink", "control_readings"))
     for m in harness.metrics_of(name, "per_layer"):
         assert callable(harness.reader(m["name"]))
     assert common.program_config(cell.config).cores == \
         cell.config["fabric"]["cores"]
-    assert cell.config["reduced"] == []
+    entry = next(c for c in BENCH["configs"]
+                 if c["name"] == cell.config["name"])
+    assert harness.cut_faults(entry, cell.config) == []
 
 
 def canned_trace():
@@ -86,7 +90,10 @@ def canned_trace():
             "device_ops": [["fusion.1 f32[4]", 0.5]],
             "idle_gaps": [["bench.call", 0.4]],
             "spans": {"interface.run_batched": [0.001, 0.003],
-                      "serve.step": [0.004]}}
+                      "serve.step": [0.004],
+                      "serve.pump": [0.006, 0.001],
+                      "serve.pump.wait": [0.002],
+                      "serve.lock_wait": [0.0001]}}
 
 
 @pytest.fixture(scope="module", params=CELLS)

@@ -6,8 +6,9 @@ from chip import harness
 
 
 def shrink(cell: harness.Cell) -> harness.Cell:
-    """A copy of ``cell`` at 64 neurons a core, 64 CAM entries, and a few
-    lanes, ticks and requests; the layout (chips, scheme, NoC) is kept."""
+    """A copy of ``cell`` at 64 neurons a core, 64 CAM entries, and the
+    few lanes, ticks or requests its driver's ``shrink`` gives; the layout
+    (chips, scheme, NoC) is kept."""
     cell = copy.deepcopy(cell)
     fab = cell.config["fabric"]
     fab.update(neurons_per_core=64, cam_entries_per_core=64)
@@ -15,12 +16,7 @@ def shrink(cell: harness.Cell) -> harness.Cell:
         fab.update(chips=2, cores_per_chip=2, cores=4)
     else:
         fab.update(cores_per_chip=2, cores=2)
-    mix = cell.mix
-    if mix["driver"] == "offline":
-        mix.update(lanes=4, ticks=16)
-    else:
-        mix.update(tenants=4, pool=16, rate_per_s=200, request_ticks=8,
-                   flush_ticks=8, trace_seconds=0.5)
+    harness.driver(cell).shrink(cell.mix)
     return cell
 
 
