@@ -12,10 +12,14 @@ accumulation.
 Chip sharding: ``run(spikes, shard="chips")`` executes the per-chip slice
 of every tick - the CAM match/scatter, the per-core arbiter latency, and
 the AER encode stage - under `repro.compat.shard_map` over a 1D
-``("chips",)`` device mesh (`repro.launch.mesh.make_chip_mesh`), one
-device per simulated chip.  On a single-device host (or whenever fewer
-devices exist than chips) the same per-chip body runs under `jax.vmap`
-instead, so results never depend on the host topology.  Both mapped paths
+``("chips",)`` device mesh (`repro.launch.mesh.make_chip_mesh`) of ``d``
+devices, ``d`` the largest divisor of ``chips`` that the host's device
+count allows: ``chips / d`` consecutive simulated chips per device, each
+device running the per-chip body under `jax.vmap` over its own chips.
+When that divisor is 1 (a single-device host, or a prime chip count above
+the device count) the same per-chip body runs under `jax.vmap` over all
+chips on one device instead, so results never depend on the host
+topology.  Both mapped paths
 reassemble the per-core vectors in fabric order and funnel through
 `pipeline.accounting_stats`: currents are bit-identical to the unsharded
 oracle on either path (and stats too under the vmap fallback); on a real
@@ -277,7 +281,8 @@ class InterfaceSession:
             fns = self._masked_fns(shard, telemetry)
             mask = self._check_mask(mask, spikes, 1)
             acc0 = StepStats.zeros() if stats0 is None else stats0
-            with obs_trace.span("interface.run", masked=True):
+            with obs_trace.span("interface.run", masked=True,
+                                **self._shard_args(shard)):
                 spikes = fns["mask_solo"](spikes, mask)
                 return fns["run"](self.params, spikes, acc0)
         if stats0 is not None:
@@ -288,7 +293,7 @@ class InterfaceSession:
             with obs_trace.span("interface.run", telemetry=telemetry):
                 return t_fn(self.params, spikes)
         if fn is not None:
-            with obs_trace.span("interface.run", shard=shard):
+            with obs_trace.span("interface.run", **self._shard_args(shard)):
                 return fn(spikes)
         with obs_trace.span("interface.run"):
             if self._all_frames_fit(spikes):
@@ -337,7 +342,8 @@ class InterfaceSession:
                 b = spikes.shape[0]
                 acc0 = jax.tree.map(
                     lambda x: jnp.zeros((b,), x.dtype), StepStats.zeros())
-            with obs_trace.span("interface.run_batched", masked=True):
+            with obs_trace.span("interface.run_batched", masked=True,
+                                **self._shard_args(shard)):
                 spikes = fns["mask"](spikes, mask)
                 return fns["run_batched"](self.params, spikes, acc0)
         if stats0 is not None:
@@ -349,7 +355,8 @@ class InterfaceSession:
             with obs_trace.span("interface.run_batched", telemetry=telemetry):
                 return t_fn(self.params, spikes)
         if fn is not None:
-            with obs_trace.span("interface.run_batched", shard=shard):
+            with obs_trace.span("interface.run_batched",
+                                **self._shard_args(shard)):
                 return fn(spikes)
         with obs_trace.span("interface.run_batched"):
             if self._all_frames_fit(spikes):
@@ -559,6 +566,18 @@ class InterfaceSession:
             self._sharded_cache = self._build_sharded()
         return self._sharded_cache[kind]
 
+    def _shard_args(self, shard: str | None) -> dict:
+        """Span args of a call: the shard mode and, on a multi-chip
+        fabric, how its chips sit on the devices.  Empty for flat calls."""
+        if shard is None:
+            return {}
+        if self.config.chips == 1:
+            return {"shard": shard}
+        self._sharded_tick()
+        devices = 1 if self.chip_mesh is None else self.chip_mesh.devices.size
+        return {"shard": shard, "devices": devices,
+                "chips_per_device": self.config.chips // devices}
+
     def _chip_body(self):
         """Per-chip tick work: local CAM match/scatter + encode stage.
 
@@ -606,31 +625,33 @@ class InterfaceSession:
             lambda x: x.reshape((chips, cpc) + x.shape[1:]),
             (self.params, self.routing.src_idx, self.routing.active))
 
-        if len(jax.devices()) >= chips:
+        # the body over a run of consecutive chips: all of them on the
+        # fallback, a device's own block of them on the mesh
+        over_chips = jax.vmap(body, in_axes=(0, 0, 0, 0, None))
+        devices = max(d for d in range(1, min(chips, len(jax.devices())) + 1)
+                      if chips % d == 0)
+        if devices > 1:
             from repro.launch import mesh as launch_mesh
             from repro.parallel import sharding as shd
 
-            mesh = launch_mesh.make_chip_mesh(chips)
-
-            def block_body(p_c, si, ac, sp_c, sp_flat):
-                # shard_map blocks keep the mapped axis with size 1
-                sq = jax.tree.map(lambda x: x[0], (p_c, si, ac, sp_c))
-                cur, lat, enc, hits = body(*sq, sp_flat)
-                return cur[None], lat[None], enc[None], hits[None]
+            with obs_trace.span("interface.chip_mesh", chips=chips,
+                                devices=devices,
+                                chips_per_device=chips // devices):
+                mesh = launch_mesh.make_chip_mesh(devices)
+                # pin the per-chip constants to their devices once, at build
+                per_chip = jax.device_put(
+                    per_chip,
+                    shd.to_named(shd.leading_axis_specs(per_chip, "chips"),
+                                 mesh))
 
             self.chip_mesh = mesh
             mapped = compat.shard_map(
-                block_body, mesh=mesh,
+                over_chips, mesh=mesh,
                 in_specs=(P("chips"), P("chips"), P("chips"), P("chips"),
                           P()),
                 out_specs=P("chips"))
-            # pin the per-chip constants to their devices once, at build
-            per_chip = jax.device_put(
-                per_chip,
-                shd.to_named(shd.leading_axis_specs(per_chip, "chips"),
-                             mesh))
         else:
-            mapped = jax.vmap(body, in_axes=(0, 0, 0, 0, None))
+            mapped = over_chips
 
         p_chips, src_idx, active = per_chip
         tables, cam_cycle_ns = self.tables, self.cam_cycle_ns
@@ -698,7 +719,8 @@ class InterfaceSession:
     def state_sharding(self, shard: str | None = None):
         """Where state carried across calls (accumulators) should live.
 
-        One device for flat runs; replicated over the chip mesh for
+        One device for flat runs; replicated over the chip mesh (all of
+        its devices, each holding ``chips / devices`` simulated chips) for
         ``shard="chips"`` runs that take the mesh, so the carry sits on
         the same devices as the per-chip constants the sharded tick
         closes over (a jit refuses arguments committed elsewhere).

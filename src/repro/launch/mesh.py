@@ -34,13 +34,14 @@ def make_host_mesh(data: int = 1, model: int = 1):
     return jax.make_mesh((data, model), ("data", "model"), **_axis_kwargs(2))
 
 
-def make_chip_mesh(chips: int):
-    """1D ``("chips",)`` device mesh for sharded interface sessions.
+def make_chip_mesh(devices: int):
+    """1D ``("chips",)`` mesh over ``devices`` devices for sharded sessions.
 
-    One device per simulated neuromorphic chip
-    (`InterfaceSession.run(shard="chips")`); callers fall back to vmap
-    when fewer devices exist than chips."""
+    `InterfaceSession.run(shard="chips")` gives each device ``chips /
+    devices`` consecutive simulated neuromorphic chips, so ``devices`` is a
+    divisor of the simulated chip count; callers fall back to vmap on one
+    device when no divisor above 1 fits the host."""
     n = len(jax.devices())
-    if chips > n:
-        raise ValueError(f"need {chips} devices for a chip mesh, have {n}")
-    return jax.make_mesh((chips,), ("chips",), **_axis_kwargs(1))
+    if devices > n:
+        raise ValueError(f"need {devices} devices for a chip mesh, have {n}")
+    return jax.make_mesh((devices,), ("chips",), **_axis_kwargs(1))

@@ -14,6 +14,7 @@ Covers the PR acceptance criteria:
 """
 
 import dataclasses
+import json
 import os
 import subprocess
 import sys
@@ -259,6 +260,120 @@ def test_sharded_serve_group_on_fake_mesh_matches_solo():
                        text=True, timeout=900, env=env)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "SERVE_MESH_OK" in r.stdout
+
+
+# ---- several simulated chips per device -------------------------------------
+
+BLOCKS_RUN = """
+import json
+import jax, jax.numpy as jnp, numpy as np
+assert len(jax.devices()) == 4, jax.devices()
+from repro.core import fabric
+from repro.interface import Interface, InterfaceConfig, StepStats
+from repro.serve import ServeEngine, TenantSpec, default_connectivity
+
+def cfg_of(chips):
+    return InterfaceConfig(chips=chips, cores_per_chip=2, neurons_per_core=16,
+                           cam_entries_per_core=32)
+
+def stats_gap(a, b):
+    return max(float(jnp.max(jnp.abs(getattr(a, f) - getattr(b, f))
+                             / jnp.maximum(1.0, jnp.abs(getattr(a, f)))))
+               for f in StepStats._fields)
+
+out = {}
+for chips in (16, 8, 6):
+    cfg = cfg_of(chips)
+    s = Interface(cfg).compile(
+        fabric.random_connectivity(jax.random.PRNGKey(chips), cfg))
+    sp = jax.random.bernoulli(jax.random.PRNGKey(1), 0.25,
+                              (2, 3, cfg.cores, 16))
+    cur, acc = s.run(sp[0])
+    cur_s, acc_s = s.run(sp[0], shard="chips")
+    cb, ab = s.run_batched(sp)
+    cbs, abs_ = s.run_batched(sp, shard="chips")
+    out[chips] = {"mesh": s.chip_mesh.devices.size,
+                  "run_currents": bool(jnp.all(cur == cur_s)),
+                  "batched_currents": bool(jnp.all(cb == cbs)),
+                  "stats_gap": max(stats_gap(acc, acc_s),
+                                   stats_gap(ab, abs_)),
+                  "replicated": len(s.state_sharding("chips").device_set)}
+
+cfg = cfg_of(16)
+engine = ServeEngine(flush_ticks=4, flush_deadline_s=0.0, keep_currents=True)
+specs = [TenantSpec(f"s{i}", cfg, seed=i, shard="chips") for i in range(2)]
+for spec in specs:
+    engine.register(spec)
+    engine.submit_scenario(spec.name, 6)
+engine.drain()
+group = next(iter(engine.groups.values()))
+solo = Interface(cfg).compile(default_connectivity(cfg, 0))
+out["serve"] = {
+    "mesh": group.session.chip_mesh.devices.size,
+    "matches_solo": all(
+        np.array_equal(engine.currents(spec.name),
+                       np.asarray(solo.run(spec.stream(6, round=0))[0]))
+        for spec in specs),
+    "closes": bool(engine.accounting()["closes"])}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def blocks_run():
+    """Sharded sessions with more simulated chips than the 4 fake CPU
+    devices of a child process; the JSON of what each one did."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", BLOCKS_RUN], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("chips", [16, 8])
+def test_several_chips_per_device_match_the_unsharded_session(blocks_run, chips):
+    """16 and 8 simulated chips on 4 devices: a mesh of all 4, each device
+    holding chips / 4 of them; currents bit-identical to the unsharded
+    session, stats to float tolerance."""
+    got = blocks_run[str(chips)]
+    assert got["mesh"] == 4 and got["replicated"] == 4
+    assert got["run_currents"] and got["batched_currents"]
+    assert got["stats_gap"] <= 1e-5, got
+
+
+def test_mesh_is_the_largest_divisor_of_the_chips_that_fits(blocks_run):
+    """6 chips on 4 devices take a mesh of 3, two chips a device."""
+    got = blocks_run["6"]
+    assert got["mesh"] == 3 and got["replicated"] == 3
+    assert got["run_currents"] and got["batched_currents"]
+    assert got["stats_gap"] <= 1e-5, got
+
+
+def test_sharded_serve_group_of_16_chips_on_4_devices_matches_solo(blocks_run):
+    got = blocks_run["serve"]
+    assert got == {"mesh": 4, "matches_solo": True, "closes": True}
+
+
+def test_one_device_keeps_the_vmap_fallback():
+    cfg = _cfg(chips=16, cores=32)
+    session = Interface(cfg).compile(fabric.random_connectivity(KEY, cfg))
+    spikes = jax.random.bernoulli(jax.random.PRNGKey(7), 0.3,
+                                  (2, 2, cfg.cores, cfg.neurons_per_core))
+    cur, _ = session.run_batched(spikes)
+    cur_s, _ = session.run_batched(spikes, shard="chips")
+    assert session.chip_mesh is None
+    assert bool(jnp.all(cur == cur_s))
+
+
+def test_chip_mesh_asks_for_devices_not_chips():
+    from repro.launch import mesh as launch_mesh
+
+    n = len(jax.devices())
+    assert launch_mesh.make_chip_mesh(n).devices.size == n
+    with pytest.raises(ValueError, match=f"need {n + 1} devices"):
+        launch_mesh.make_chip_mesh(n + 1)
 
 
 # ---- hierarchy tables & routing index ---------------------------------------

@@ -22,6 +22,8 @@ import dataclasses
 import glob
 import json
 import math
+import os
+import subprocess
 import sys
 import threading
 import time
@@ -32,7 +34,7 @@ import numpy as np
 import pytest
 
 from repro.core import fabric
-from repro.interface import Interface, StepStats
+from repro.interface import Interface, InterfaceConfig, StepStats
 from repro.obs import metrics as obs_metrics
 from repro.obs import report as obs_report
 from repro.obs import telemetry as obs_telemetry
@@ -307,6 +309,57 @@ def test_session_compile_and_run_emit_spans():
     assert compile_ev["args"]["cores"] == cfg.cores
     telem_ev = [e for e in tracer.events if e["args"].get("telemetry") == "ticks"]
     assert len(telem_ev) == 1
+
+
+CHIP_MESH_SPANS = """
+import json
+import jax
+assert len(jax.devices()) == 4, jax.devices()
+from repro.core import fabric
+from repro.interface import Interface, InterfaceConfig
+from repro.obs import trace as obs_trace
+cfg = InterfaceConfig(chips=16, cores_per_chip=2, neurons_per_core=16,
+                      cam_entries_per_core=32)
+session = Interface(cfg).compile(
+    fabric.random_connectivity(jax.random.PRNGKey(0), cfg))
+spikes = jax.random.bernoulli(jax.random.PRNGKey(1), 0.25,
+                              (2, 3, cfg.cores, 16))
+tracer = obs_trace.Tracer()
+with tracer:
+    session.run_batched(spikes, shard="chips")
+print(json.dumps([[e["name"], e["args"]] for e in tracer.events]))
+"""
+
+
+def test_sharded_run_batched_spans_name_the_chip_layout():
+    """16 simulated chips on 4 fake devices: the mesh is built under
+    ``interface.chip_mesh``, before the first sharded call's span opens,
+    and the call's span says 4 chips a device."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", CHIP_MESH_SPANS],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr[-4000:]
+    events = json.loads(r.stdout.strip().splitlines()[-1])
+    layout = {"devices": 4, "chips_per_device": 4}
+    assert events == [
+        ["interface.chip_mesh", dict(chips=16, depth=0, **layout)],
+        ["interface.run_batched", dict(shard="chips", depth=0, **layout)]]
+
+
+def test_vmap_fallback_span_puts_every_chip_on_one_device():
+    cfg = InterfaceConfig(chips=2, cores_per_chip=2, neurons_per_core=16,
+                          cam_entries_per_core=32)
+    session = _session(cfg)
+    tracer = obs_trace.Tracer()
+    with tracer:
+        session.run_batched(_spikes(cfg, lead=(2,)), shard="chips")
+        session.run_batched(_spikes(cfg, lead=(2,)))
+    assert [(e["name"], e["args"]) for e in tracer.events] == [
+        ("interface.run_batched", {"shard": "chips", "devices": 1,
+                                   "chips_per_device": 2, "depth": 0}),
+        ("interface.run_batched", {"depth": 0})]
 
 
 # ---- metrics ---------------------------------------------------------------
